@@ -29,6 +29,7 @@ import numpy as np
 from repro.core import layouts, make_templates, generate_workload
 from repro.core import workload as wl
 from repro.engine import Decision, InMemoryBackend, LayoutEngine
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def make_state_space(data: np.ndarray, num_states: int,
@@ -129,6 +130,7 @@ def main() -> None:
                     help="tiny sizes, CI sanity only")
     ap.add_argument("--out", default="BENCH_decision_loop.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     rng = np.random.default_rng(0)
     if args.smoke:

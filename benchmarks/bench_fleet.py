@@ -47,6 +47,7 @@ from repro.core.workload import make_drift_scenario
 from repro.engine import (Decision, FleetEngine, InMemoryBackend,
                           KConcurrentScheduler, LayoutEngine, OreoPolicy,
                           TokenBucketScheduler, UnlimitedScheduler)
+from repro.launch.compile_cache import enable_compile_cache
 
 SCENARIOS = ["sudden_shift", "gradual_drift", "cyclic_diurnal",
              "flash_crowd", "template_churn"]
@@ -252,6 +253,7 @@ def main() -> None:
                          "to T=32, tiny")
     ap.add_argument("--out", default="BENCH_fleet.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         tenants, rows, cols, qpt = 3, 2_000, 6, 150

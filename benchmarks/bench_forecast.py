@@ -47,6 +47,7 @@ from repro.engine import (FleetEngine, InMemoryBackend, IngestConfig,
                           KConcurrentScheduler, LayoutEngine, OreoPolicy,
                           TokenBucketScheduler, UnlimitedScheduler)
 from repro.forecast import ForecastConfig, ForecastPolicy
+from repro.launch.compile_cache import enable_compile_cache
 
 DRIFT = ["sudden_shift", "gradual_drift", "cyclic_diurnal", "flash_crowd",
          "template_churn"]
@@ -142,6 +143,7 @@ def main() -> None:
                     help="CI sizes: all 10 scenarios x 3 schedulers, tiny")
     ap.add_argument("--out", default="BENCH_forecast.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         tenants, rows, cols, qpt = 3, 2_000, 6, 150

@@ -43,6 +43,7 @@ from repro.core import layout_manager as lm
 from repro.core.workload import INGEST_SCENARIOS, make_ingest_scenario
 from repro.engine import (FleetEngine, InMemoryBackend, IngestConfig,
                           LayoutEngine, OreoPolicy, UnlimitedScheduler)
+from repro.launch.compile_cache import enable_compile_cache
 
 SCENARIOS = sorted(INGEST_SCENARIOS)
 
@@ -136,6 +137,7 @@ def main() -> None:
                     help="CI sizes: all ingest scenarios, small fleet")
     ap.add_argument("--out", default="BENCH_ingest.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         tenants, rows, cols, qpt = 3, 2_000, 6, 200

@@ -46,15 +46,19 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmarks import common
-from repro.kernels.decision_fused import decision_fused as df_kernel
 from repro.kernels.decision_fused import ops as df_ops
 from repro.kernels.fleet_scan import ref as fs_ref
 from repro.kernels.move_score import ref as ms_ref
 from repro.kernels.pruning import ref as prune_ref
 from repro.kernels.zorder import ref as z_ref
+from repro.launch import roofline
+from repro.launch.compile_cache import enable_compile_cache
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s
+# The analytic rooflines of run() are for the modelled chip, whatever the
+# host.
+_PEAKS = roofline.device_peaks(roofline.TARGET_DEVICE_KIND)
+PEAK_FLOPS = _PEAKS["bf16_flops"]
+HBM_BW = _PEAKS["hbm_bytes_per_s"]
 
 
 def _time(f, *args, iters: int = 5, **kw):
@@ -146,7 +150,8 @@ def bench_interpret_lane(seed: int = 0) -> Dict:
     ops = _fused_operands(B, T, S, P, C, W, seed)
 
     def kernel(*a):
-        return df_kernel.fused_decision_pallas(*a, bt=2, bp=4, interpret=True)
+        return df_ops.fused_decision(*a, use_kernel=True, bb=2,
+                                     interpret=True)
 
     s = _time(kernel, *ops, iters=2)
     k_scan, k_cost, k_freq = kernel(*ops)
@@ -178,7 +183,7 @@ def bench_compiled_pallas_lane(B: int, T: int, S: int, P: int, C: int, W: int,
     ops = _fused_operands(B, T, S, P, C, W, seed)
 
     def kernel(*a):
-        return df_kernel.fused_decision_pallas(*a, interpret=False)
+        return df_ops.fused_decision(*a, use_kernel=True, interpret=False)
 
     fused_s = _time(kernel, *ops, iters=reps)
     sep_s = _time(_separate_passes, *ops, iters=reps)
@@ -267,6 +272,7 @@ def main() -> None:
                          "lane, small")
     ap.add_argument("--out", default="BENCH_kernels.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         cells = [dict(B=16, T=8, S=8, P=64, C=8, W=32)]

@@ -47,6 +47,7 @@ from repro.core.workload import make_drift_scenario
 from repro.engine import (FleetEngine, InMemoryBackend, LayoutEngine,
                           OreoPolicy, TokenBucketScheduler,
                           UnlimitedScheduler)
+from repro.launch.compile_cache import enable_compile_cache
 
 SCENARIOS = ["sudden_shift", "gradual_drift", "cyclic_diurnal",
              "flash_crowd", "template_churn"]
@@ -173,6 +174,7 @@ def main() -> None:
                     help="CI sizes: all scenarios x {unlimited, bucket}")
     ap.add_argument("--out", default="BENCH_reorg.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         tenants, rows, cols, qpt = 3, 2_000, 6, 150

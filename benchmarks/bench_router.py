@@ -54,6 +54,7 @@ from repro.core import layout_manager as lm
 from repro.core.workload import make_drift_scenario
 from repro.engine import FleetEngine, FleetRouter, InMemoryBackend, \
     LayoutEngine, OreoPolicy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.shard_host import ProcessShardSet
 
 SCENARIO = "sudden_shift"
@@ -177,7 +178,7 @@ def parallel_cell(factories, fs, num_shards: int) -> Dict:
         t0 = time.perf_counter()
         for ev in fs:
             procs.submit(ev)
-        procs.drain()
+        procs.drain(compute="numpy")    # workers never reach for a chip
         wall = time.perf_counter() - t0
         result = procs.result()
     assert result.ticks == len(fs)
@@ -205,6 +206,7 @@ def main() -> None:
                     help="skip the process-parallel lane (informative "
                          "only; spawning workers is slow on tiny runners)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         tenants, rows, cols, qpt = 16, 1_500, 5, 100

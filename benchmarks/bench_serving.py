@@ -52,6 +52,7 @@ from repro.engine import (FleetEngine, InMemoryBackend, IngestConfig,
                           KConcurrentScheduler, LayoutEngine, OreoPolicy,
                           UnlimitedScheduler)
 from repro.serve import FrontendConfig, ServeFrontend
+from repro.launch.compile_cache import enable_compile_cache
 
 SCENARIOS = ("flash_crowd", "ingest_burst")
 INGEST_SCENARIOS = ("ingest_burst",)
@@ -242,6 +243,7 @@ def main() -> None:
                          "ceiling); never use for a checked-in baseline")
     ap.add_argument("--chaos-seconds", type=float, default=0.002)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         tenants, rows, cols, qpt = 3, 2_000, 6, 150

@@ -34,4 +34,6 @@ def run(quick: bool = False) -> List[str]:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("\n".join(run()))

@@ -22,6 +22,8 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated subset, e.g. fig3,table1")
     args, _ = ap.parse_known_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import (appendix_multicopy, bench_kernels,
                             fig3_end_to_end, fig4_gap_to_optimal,

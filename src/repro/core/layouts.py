@@ -36,7 +36,11 @@ class PartitionMetadata:
 
     @property
     def total_rows(self) -> int:
-        return int(self.rows.sum())
+        # Rounded, not truncated: sample-estimated metadata scales counts by
+        # a non-integer n/m, so the float sum lands within rounding noise of
+        # n on either side, and truncating n - 1e-6 to n - 1 would price a
+        # full scan above 1.0.
+        return int(round(float(self.rows.sum())))
 
 
 def metadata_from_assignment(data: np.ndarray, assignment: np.ndarray,

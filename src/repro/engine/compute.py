@@ -156,9 +156,9 @@ def fleet_scan_matrix(q_lo: np.ndarray, q_hi: np.ndarray, mins: np.ndarray,
                                      backend="numpy")
         if backend == "pallas":
             return _fleet_scan_pallas(q_lo, q_hi, mins, maxs)
-        return np.asarray(fused_frames_scan(
-            q_lo[None], q_hi[None], mins[:, None, :, :],
-            maxs[:, None, :, :]))[0, :, 0, :]
+        return fused_frames_scan(
+            q_lo[None], q_hi[None], np.moveaxis(mins, -1, 0)[:, :, None],
+            np.moveaxis(maxs, -1, 0)[:, :, None])[0, :, 0, :]
     raise ValueError(f"unknown compute backend: {backend!r} "
                      f"(expected one of {BACKENDS})")
 
@@ -185,29 +185,35 @@ def _scan_matrix_pallas(q_lo, q_hi, mins, maxs) -> np.ndarray:
     return np.asarray(out) > 0.5
 
 
-def fused_frames_scan(q_lo: np.ndarray, q_hi: np.ndarray, p_min: np.ndarray,
-                      p_max: np.ndarray) -> np.ndarray:
-    """(B, T, C) frame bounds x (T, S, P, C) plane -> (B, T, S, P) bool.
+def fused_frames_scan(q_lo: np.ndarray, q_hi: np.ndarray, minsT: np.ndarray,
+                      maxsT: np.ndarray) -> np.ndarray:
+    """(B, T, C) frame bounds x (C, T, S, P) plane -> (B, T, S, P) bool.
 
     One megakernel launch scores every frame of a batched pass for every
     tenant — the ``pallas_fused`` replacement for B separate
-    :func:`fleet_scan_matrix` calls.  Operands are cast to float32;
-    callers owning the bit-identity contract must check
-    :func:`float32_exact` first (see ``FleetMatrix._scanned_all``).
+    :func:`fleet_scan_matrix` calls.  The plane is column-major, the twin
+    the packed planes keep.  Operands are cast to float32; callers owning
+    the bit-identity contract must check :func:`float32_exact` first (see
+    ``FleetMatrix._scanned_all``).  The frame count is padded up to a
+    power of two, so passes of varying size compile a handful of kernel
+    shapes rather than one per size.
     """
     import jax.numpy as jnp
 
     from repro.kernels.decision_fused import decision_fused
 
+    b = q_lo.shape[0]
+    pad = ((0, (1 << (b - 1).bit_length()) - b), (0, 0), (0, 0))
     scan, _, _ = decision_fused.fused_decision_pallas(
-        jnp.asarray(q_lo, jnp.float32), jnp.asarray(q_hi, jnp.float32),
-        jnp.asarray(p_min, jnp.float32), jnp.asarray(p_max, jnp.float32))
-    return np.asarray(scan) > 0.5
+        jnp.asarray(np.pad(q_lo, pad), jnp.float32),
+        jnp.asarray(np.pad(q_hi, pad), jnp.float32),
+        jnp.asarray(minsT, jnp.float32), jnp.asarray(maxsT, jnp.float32))
+    return np.asarray(scan[:b] > 0.5)
 
 
 def _scan_matrix_fused(q_lo, q_hi, mins, maxs) -> np.ndarray:
     # (Q, C) x (P, C) through the megakernel: Q query frames of a single
     # tenant whose plane has one state of P partitions.
     out = fused_frames_scan(q_lo[:, None, :], q_hi[:, None, :],
-                            mins[None, None, :, :], maxs[None, None, :, :])
+                            mins.T[:, None, None, :], maxs.T[:, None, None, :])
     return out[:, 0, 0, :]

@@ -354,7 +354,7 @@ class FleetMatrix:
             if (self._plane_float32_exact()
                     and compute.float32_exact(q_lo, q_hi)):
                 return compute.fused_frames_scan(q_lo, q_hi,
-                                                 self._mins, self._maxs)
+                                                 self._minsT, self._maxsT)
             warnings.warn(
                 "FleetMatrix(pallas_fused): operands are not exactly "
                 "float32-representable; using the exact numpy fused pass",

@@ -148,33 +148,44 @@ def scan_frequencies(metas: Sequence[L.PartitionMetadata],
     the layouts into one padded ``(S, P_max, C)`` plane and scores all
     (state, partition) move candidates in a single
     :func:`repro.kernels.move_score.ops.move_scan_frequencies` launch;
-    ``"pallas_fused"`` routes the same plane through the decision
-    megakernel's ``freq`` output (both float32 — ordering heuristic only,
-    never cost accounting).
+    ``"pallas_fused"`` routes the same plane, column-major, through the
+    decision megakernel's ``freq`` output.  Both compare in float32 —
+    ordering heuristic only, never cost accounting — and the fused path
+    recovers exact window counts, so on float32-exact bounds it equals
+    the numpy path bit for bit.
     """
     if compute in ("pallas", "pallas_fused"):
         counts = [m.num_partitions for m in metas]
         p_max = max(counts) if counts else 0
         s, c = len(metas), metas[0].num_columns
-        mins = np.full((s, p_max, c), np.inf, dtype=np.float32)
-        maxs = np.full((s, p_max, c), -np.inf, dtype=np.float32)
-        for k, m in enumerate(metas):
-            mins[k, :counts[k]] = m.mins
-            maxs[k, :counts[k]] = m.maxs
         if compute == "pallas_fused":
             # The megakernel's freq output over a single-tenant plane
-            # (T=1, S layouts, P_max partitions): the (Q, C) sample is the
-            # recent-query window, and the same launch could also carry
-            # the scoring outputs for the planning tenant.
+            # (T=1, S layouts, P_max partitions), column-major: the (Q, C)
+            # sample is the recent-query window, and the same launch could
+            # also carry the scoring outputs for the planning tenant.
             from repro.kernels.decision_fused import decision_fused
+            mins = np.full((c, 1, s, p_max), np.inf, dtype=np.float32)
+            maxs = np.full((c, 1, s, p_max), -np.inf, dtype=np.float32)
+            for k, m in enumerate(metas):
+                mins[:, 0, k, :counts[k]] = m.mins.T
+                maxs[:, 0, k, :counts[k]] = m.maxs.T
             dummy = np.zeros((1, 1, c), dtype=np.float32)
             _, _, freq = decision_fused.fused_decision_pallas(
                 dummy + 1.0, dummy,          # empty frame query: unused
-                mins[None], maxs[None],
+                mins, maxs,
                 w_lo=q_lo.astype(np.float32), w_hi=q_hi.astype(np.float32),
                 emit_scan=False)
-            freq = np.asarray(freq)[0]                       # (S, P_max)
+            # The float32 mean is within an ulp of count / Q whatever the
+            # device's division, so rounding recovers the exact count and
+            # the float64 mean the numpy path computes.
+            n_q = len(q_lo)
+            freq = np.rint(np.asarray(freq)[0].astype(np.float64) * n_q) / n_q
         else:
+            mins = np.full((s, p_max, c), np.inf, dtype=np.float32)
+            maxs = np.full((s, p_max, c), -np.inf, dtype=np.float32)
+            for k, m in enumerate(metas):
+                mins[k, :counts[k]] = m.mins
+                maxs[k, :counts[k]] = m.maxs
             from repro.kernels.move_score import ops as ms_ops
             freq = np.asarray(ms_ops.move_scan_frequencies(
                 q_lo.astype(np.float32), q_hi.astype(np.float32), mins,

@@ -64,6 +64,10 @@ class StateMatrix:
         self._uniform = True    # all counts == P_cap -> batched reduction
         #: Bumped on every register/deregister; consumers may key caches on it.
         self.version = 0
+        # Cached float32-representability of the plane, keyed on version
+        # (pallas_fused bit-identity guard).
+        self._f32_version = -1
+        self._f32_exact = False
         #: Mirror hooks (see :class:`repro.engine.fleet_matrix.FleetMatrix`):
         #: each listener's ``on_register(state_id, meta)`` /
         #: ``on_deregister(state_id)`` fires *after* the plane update, in the
@@ -246,7 +250,22 @@ class StateMatrix:
     def _scanned(self, q_lo: np.ndarray, q_hi: np.ndarray) -> np.ndarray:
         """(n, P_cap) bool scan matrix over all registered states."""
         n = self._n
-        if self.compute_backend in ("pallas", "pallas_fused"):
+        if self.compute_backend == "pallas_fused":
+            # The whole (C, S_cap, P_cap) twin as a one-tenant plane:
+            # padded slots carry [+inf, -inf] bounds and are never scanned,
+            # and a shape fixed between capacity growths keeps the kernel
+            # from recompiling as states come and go.  Same float32 guard
+            # as the other kernel paths.
+            if (self._plane_float32_exact()
+                    and compute.float32_exact(q_lo, q_hi)):
+                return compute.fused_frames_scan(
+                    q_lo[None, None], q_hi[None, None],
+                    self._minsT[:, None], self._maxsT[:, None])[0, 0, :n]
+            warnings.warn(
+                "StateMatrix(pallas_fused): operands are not exactly "
+                "float32-representable; using the exact numpy pass",
+                RuntimeWarning, stacklevel=3)
+        elif self.compute_backend == "pallas":
             mins2d = self._mins[:n].reshape(n * self._pcap, self._c)
             maxs2d = self._maxs[:n].reshape(n * self._pcap, self._c)
             return compute.scan_matrix(q_lo[None], q_hi[None], mins2d,
@@ -255,6 +274,13 @@ class StateMatrix:
                                        )[0].reshape(n, self._pcap)
         return compute.masked_overlap(self._minsT[:, :n, :],
                                       self._maxsT[:, :n, :], q_lo, q_hi)
+
+    def _plane_float32_exact(self) -> bool:
+        """Cached-per-version float32-representability of the plane."""
+        if self._f32_version != self.version:
+            self._f32_version = self.version
+            self._f32_exact = compute.float32_exact(self._minsT, self._maxsT)
+        return self._f32_exact
 
     def reduce_scanned(self, scanned: np.ndarray) -> np.ndarray:
         """Row-weighted reduction of an (n, P_cap) scan matrix to (n,) costs.

@@ -13,6 +13,7 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+from jax._src import xla_bridge
 
 
 def default_backend() -> str:
@@ -21,6 +22,16 @@ def default_backend() -> str:
     Thin indirection over :func:`jax.default_backend` so tests can
     monkeypatch the detected platform without touching global JAX state.
     """
+    return jax.default_backend()
+
+
+def initialized_platform() -> Optional[str]:
+    """The platform this process's JAX backend runs on, or None when no
+    backend has been initialized yet — i.e. when this process holds no
+    device.  Unlike :func:`default_backend` it never initializes one.
+    """
+    if not xla_bridge.backends_are_initialized():
+        return None
     return jax.default_backend()
 
 

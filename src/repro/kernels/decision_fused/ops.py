@@ -11,6 +11,7 @@ import functools
 from typing import Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 
 from repro.kernels.decision_fused import decision_fused, ref
 
@@ -24,13 +25,15 @@ def fused_decision(q_lo, q_hi, p_min, p_max, rows=None, inv_totals=None,
 
     ``cost`` requires ``rows`` (T, S, P) and ``inv_totals`` (T, S);
     ``freq`` requires the (W, C) recent-query window bounds.  Elements of
-    the triple not requested come back ``None``.
+    the triple not requested come back ``None``.  The kernel takes the
+    plane column-major, so the bounds are transposed on the way in.
     """
     if not use_kernel:
         return _ref_call(q_lo, q_hi, p_min, p_max, rows, inv_totals,
                          w_lo, w_hi)
     return decision_fused.fused_decision_pallas(
-        q_lo, q_hi, p_min, p_max, rows, inv_totals, w_lo, w_hi, **block_kw)
+        q_lo, q_hi, jnp.moveaxis(p_min, -1, 0), jnp.moveaxis(p_max, -1, 0),
+        rows, inv_totals, w_lo, w_hi, **block_kw)
 
 
 @functools.partial(jax.jit, static_argnames=())

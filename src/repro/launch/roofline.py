@@ -3,9 +3,12 @@
 Per (arch x shape x mesh) cell, derives the three per-device roofline terms
 from the trip-count-weighted HLO analysis (hlo_cost.py):
 
-    compute    = flops_per_device     / PEAK_FLOPS      (197 TFLOP/s bf16)
-    memory     = bytes_per_device     / HBM_BW          (819 GB/s)
-    collective = coll_bytes_per_device/ LINK_BW         (~50 GB/s/link ICI)
+    compute    = flops_per_device     / peak bf16 FLOP/s
+    memory     = bytes_per_device     / peak HBM bytes/s
+    collective = coll_bytes_per_device/ ICI bytes/s per link
+
+with the peaks of the modelled chip (:data:`TARGET_DEVICE_KIND`, looked up
+in :data:`DEVICE_PEAKS`),
 
 plus MODEL_FLOPS (6*N*D train / 2*N*D inference, N = active params) and the
 useful-compute ratio MODEL_FLOPS / (HLO flops x chips), which catches remat
@@ -26,11 +29,30 @@ import json
 import os
 from typing import Dict, List
 
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-LINK_BW = 50e9               # bytes/s per ICI link
+from repro.configs.base import SHAPES, get_arch
 
-from repro.configs.base import SHAPES, get_arch  # noqa: E402
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+#: TPU v5e (Google Cloud documentation, "TPU v5e"): 197 TFLOP/s bf16,
+#: 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of interconnect over 4 ICI
+#: links (50 GB/s each).  A device not listed here has no peaks:
+#: :func:`device_peaks` raises rather than assume another chip's.
+DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9,
+                    "hbm_bytes": 16e9, "ici_link_bytes_per_s": 50e9},
+}
+
+#: The chip the dry-run meshes model (a v5e pod slice).
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
+
+def device_peaks(device_kind: str) -> Dict[str, float]:
+    """Published peaks of one chip of ``device_kind`` (KeyError if unknown)."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; add them to DEVICE_PEAKS with "
+                       f"their source") from None
 
 
 def model_flops(arch: str, shape_name: str) -> float:
@@ -87,11 +109,13 @@ def ideal_bytes(arch: str, shape_name: str, opt_dtype: str = "float32"
 
 
 def analyze_record(rec: Dict) -> Dict:
+    peaks = device_peaks(TARGET_DEVICE_KIND)
+    flops_peak, hbm_bw = peaks["bf16_flops"], peaks["hbm_bytes_per_s"]
     hc = rec["hlo_cost"]
     chips = rec["num_devices"]
-    compute_s = hc["flops_per_device"] / PEAK_FLOPS
-    memory_s = hc["bytes_per_device"] / HBM_BW
-    coll_s = hc["collective_bytes_per_device"] / LINK_BW
+    compute_s = hc["flops_per_device"] / flops_peak
+    memory_s = hc["bytes_per_device"] / hbm_bw
+    coll_s = hc["collective_bytes_per_device"] / peaks["ici_link_bytes_per_s"]
     terms = {"compute": compute_s, "memory": memory_s, "collective": coll_s}
     dominant = max(terms, key=terms.get)
     mf = model_flops(rec["arch"], rec["shape"])
@@ -101,7 +125,7 @@ def analyze_record(rec: Dict) -> Dict:
     ib = ideal_bytes(rec["arch"], rec["shape"], opt_dtype)
     # The achievable step-time floor is the max of the compute ideal and the
     # memory ideal; roofline fraction = floor / modeled dominant term.
-    ideal_s = max(mf / chips / PEAK_FLOPS, ib / chips / HBM_BW)
+    ideal_s = max(mf / chips / flops_peak, ib / chips / hbm_bw)
     roofline_fraction = ideal_s / max(max(terms.values()), 1e-12)
     return {
         "arch": rec["arch"], "shape": rec["shape"], "mesh": rec["mesh"],

@@ -92,6 +92,24 @@ class ShardHostError(RuntimeError):
     """A shard worker raised; carries the worker-side traceback."""
 
 
+def _check_worker_compute(compute: str) -> None:
+    """Refuse a kernel compute path that no worker could run.
+
+    A TPU belongs to one process at a time: while this (parent) process
+    holds the TPU backend, a worker asked for a ``pallas*`` path would
+    fail or hang reaching for the chip.
+    """
+    if not compute.startswith("pallas"):
+        return
+    from repro.kernels import _backend
+    if _backend.initialized_platform() == "tpu":
+        raise RuntimeError(
+            f"shard workers cannot run compute={compute!r}: this process "
+            f"holds the TPU, and a chip serves one process at a time; "
+            f"drain worker shards with compute='numpy', or run the shards "
+            f"in this process (repro.engine.FleetRouter)")
+
+
 class ShardHost:
     """One fleet shard behind a spawned worker process.
 
@@ -148,6 +166,7 @@ class ShardHost:
 
     def start_drain(self, **kwargs) -> None:
         """Flush buffered submits and tell the worker to drain (async)."""
+        _check_worker_compute(kwargs.get("compute", "numpy"))
         self.flush_submits()
         self._conn.send(("drain", kwargs))
         self._busy = True

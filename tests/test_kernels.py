@@ -412,6 +412,13 @@ def _fused_case(B, T, S, P, C, W, seed):
     return q_lo, q_hi, p_min, p_max, rows, inv, w_lo, w_hi
 
 
+def _col_major(q_lo, q_hi, p_min, p_max, *rest):
+    """The kernel's operand order with the (T, S, P, C) plane as
+    (C, T, S, P); the oracle keeps the row-major form."""
+    return (q_lo, q_hi, np.moveaxis(p_min, -1, 0),
+            np.moveaxis(p_max, -1, 0), *rest)
+
+
 def _assert_fused_triple(got, want):
     g_scan, g_cost, g_freq = got
     w_scan, w_cost, w_freq = want
@@ -428,24 +435,23 @@ def _assert_fused_triple(got, want):
 ])
 def test_fused_decision_matches_ref(B, T, S, P, C, W):
     ops = _fused_case(B, T, S, P, C, W, B * 1000 + T * 100 + P)
-    got = df.fused_decision_pallas(*ops, interpret=True)
+    got = df.fused_decision_pallas(*_col_major(*ops), interpret=True)
     want = df_ref.fused_decision(*[jnp.asarray(a) for a in ops])
     _assert_fused_triple(got, want)
 
 
-@pytest.mark.parametrize("B,T,S,P,C,W,bt,bp,col_chunk", [
-    (2, 17, 2, 130, 7, 4, 4, 128, 8),   # T and P ragged vs the block sizes
-    (3, 5, 3, 33, 5, 6, 2, 16, 2),      # ragged everywhere, C % chunk != 0
-    (2, 8, 2, 64, 9, 8, 4, 32, 4),      # C not a multiple of col_chunk
-    (1, 1, 1, 3, 1, 1, 4, 128, 8),      # tiny: blocks clamp to the problem
-    (2, 8, 2, 128, 8, 4, 4, 128, 8),    # exact multiples (no padding)
+@pytest.mark.parametrize("B,T,S,P,C,W,bb,bp", [
+    (2, 17, 2, 130, 7, 4, 4, 128),      # P ragged vs the partition block
+    (3, 5, 3, 33, 5, 6, 2, 128),        # B ragged vs the frame block
+    (5, 8, 2, 300, 9, 8, 2, 256),       # B and P both ragged
+    (1, 1, 1, 3, 1, 1, 4, 128),         # tiny: blocks clamp to the problem
+    (4, 8, 2, 256, 8, 4, 2, 128),       # exact multiples (no padding)
 ])
-def test_fused_decision_ragged_padding_parity(B, T, S, P, C, W, bt, bp,
-                                              col_chunk):
-    """Megakernel == jnp oracle on every ragged T/P/C padding edge, with
+def test_fused_decision_ragged_padding_parity(B, T, S, P, C, W, bb, bp):
+    """Megakernel == jnp oracle on every ragged B/P padding edge, with
     interpret auto-selected (None -> interpreter on CPU-only hosts)."""
     ops = _fused_case(B, T, S, P, C, W, T * 7919 + P * 31 + C)
-    got = df.fused_decision_pallas(*ops, bt=bt, bp=bp, col_chunk=col_chunk,
+    got = df.fused_decision_pallas(*_col_major(*ops), bb=bb, bp=bp,
                                    interpret=None)
     want = df_ref.fused_decision(*[jnp.asarray(a) for a in ops])
     _assert_fused_triple(got, want)
@@ -454,8 +460,8 @@ def test_fused_decision_ragged_padding_parity(B, T, S, P, C, W, bt, bp,
 def test_fused_decision_partial_outputs():
     """Outputs not requested come back None; the requested ones are
     unchanged by which siblings ride along."""
-    q_lo, q_hi, p_min, p_max, rows, inv, w_lo, w_hi = _fused_case(
-        2, 4, 2, 20, 4, 6, 55)
+    q_lo, q_hi, p_min, p_max, rows, inv, w_lo, w_hi = _col_major(
+        *_fused_case(2, 4, 2, 20, 4, 6, 55))
     full = df.fused_decision_pallas(q_lo, q_hi, p_min, p_max, rows, inv,
                                     w_lo, w_hi, interpret=True)
     scan_only = df.fused_decision_pallas(q_lo, q_hi, p_min, p_max,
@@ -480,6 +486,13 @@ def test_fused_decision_partial_outputs():
                                  interpret=True)
 
 
+def test_fused_decision_rejects_unaligned_partition_block():
+    """The partition block rides the 128-wide lane axis on the chip."""
+    ops = _col_major(*_fused_case(1, 1, 1, 300, 2, 1, 3))
+    with pytest.raises(ValueError, match="multiple of 128"):
+        df.fused_decision_pallas(*ops[:4], bp=100, interpret=True)
+
+
 def test_fused_decision_matches_three_separate_kernels():
     """The megakernel's three outputs == the three kernels it fuses,
     bit for bit on the 0/1 scan and to float tolerance on the reductions."""
@@ -487,7 +500,8 @@ def test_fused_decision_matches_three_separate_kernels():
     q_lo, q_hi, p_min, p_max, rows, inv, w_lo, w_hi = _fused_case(
         B, T, S, P, C, W, 99)
     scan, cost, freq = df.fused_decision_pallas(
-        q_lo, q_hi, p_min, p_max, rows, inv, w_lo, w_hi, interpret=True)
+        *_col_major(q_lo, q_hi, p_min, p_max, rows, inv, w_lo, w_hi),
+        interpret=True)
     scan = np.asarray(scan)
     # scan: one fleet_scan launch per frame over the (T, S*P, C) plane
     pm2 = p_min.reshape(T, S * P, C)
@@ -544,6 +558,13 @@ def test_resolve_interpret_follows_detected_backend(monkeypatch):
     assert _backend.resolve_interpret(None) is True
 
 
+def test_initialized_platform_reports_without_initializing():
+    """The process-holds-a-device probe names the live backend's platform
+    once JAX has one (this test session already ran kernels)."""
+    jnp.zeros(1).block_until_ready()
+    assert _backend.initialized_platform() == jax.default_backend()
+
+
 def test_all_kernels_share_backend_seam(monkeypatch):
     """Monkeypatching the one detected-backend seam changes auto-detect
     for every kernel module (no copy-pasted detection left behind)."""
@@ -559,6 +580,8 @@ def test_all_kernels_share_backend_seam(monkeypatch):
     move_score.move_scores_pallas(q_lo, q_hi, p_min, p_max, interpret=None)
     pruning.scan_matrix_pallas(q_lo, q_hi, p_min[0], p_max[0],
                                interpret=None)
-    df.fused_decision_pallas(q_lo[None], q_hi[None], p_min[:, None],
-                             p_max[:, None], interpret=None)
+    df.fused_decision_pallas(q_lo[None], q_hi[None],
+                             np.moveaxis(p_min, -1, 0)[:, :, None],
+                             np.moveaxis(p_max, -1, 0)[:, :, None],
+                             interpret=None)
     assert len(calls) >= 4

@@ -1,4 +1,6 @@
 """Launch-layer tests: logical-spec resolution + HLO cost parser."""
+import os
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -99,3 +101,73 @@ def test_shape_bytes_parser():
     assert hlo_cost._shape_bytes("bf16[2,3]{1,0}") == 12
     assert hlo_cost._shape_bytes("(f32[4], s8[8])") == 24
     assert hlo_cost._shape_bytes("pred[]") == 1      # scalar: one element
+
+
+# ---------------------------------------------------------------------------
+# Device peaks (one table, keyed by device_kind)
+# ---------------------------------------------------------------------------
+
+def test_device_peaks_v5e_published_values():
+    from repro.launch import roofline
+    peaks = roofline.device_peaks("TPU v5 lite")
+    assert peaks["bf16_flops"] == 197e12
+    assert peaks["hbm_bytes_per_s"] == 819e9
+
+
+@pytest.mark.parametrize("kind", ["cpu", "TPU v4", "TPU v5p", ""])
+def test_device_peaks_unknown_kind_raises(kind):
+    """A device without published peaks is an error, never a default."""
+    from repro.launch import roofline
+    with pytest.raises(KeyError, match="no published peaks"):
+        roofline.device_peaks(kind)
+
+
+def test_roofline_terms_use_the_named_device():
+    from repro.launch import roofline
+    rec = {"hlo_cost": {"flops_per_device": 197e12, "bytes_per_device":
+                        819e9, "collective_bytes_per_device": 0.0,
+                        "collective_bytes_by_type": {}},
+           "num_devices": 1, "arch": "qwen3-1.7b",
+           "shape": next(iter(roofline.SHAPES)), "mesh": "16x16",
+           "kind": "train"}
+    row = roofline.analyze_record(rec)
+    assert row["compute_s"] == pytest.approx(1.0)
+    assert row["memory_s"] == pytest.approx(1.0)
+
+
+# ---------------------------------------------------------------------------
+# Persistent compilation cache location
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def restore_cache_config():
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    yield
+    for k, v in saved.items():
+        jax.config.update(k, v)
+
+
+def test_compile_cache_defaults_to_fixed_checkout_dir(monkeypatch,
+                                                      restore_cache_config):
+    from repro.launch import compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    path = compile_cache.enable_compile_cache()
+    assert path == compile_cache.CHECKOUT_CACHE_DIR
+    assert path.endswith(".jax_cache")
+    root = os.path.dirname(path)
+    assert os.path.isdir(os.path.join(root, "src", "repro"))
+    assert jax.config.jax_compilation_cache_dir == path
+    assert jax.config.jax_persistent_cache_min_compile_time_secs \
+        == compile_cache.MIN_COMPILE_SECONDS
+
+
+def test_compile_cache_leaves_env_dir_to_jax(monkeypatch, tmp_path,
+                                             restore_cache_config):
+    """With JAX_COMPILATION_CACHE_DIR set, no other directory is set."""
+    from repro.launch import compile_cache
+    jax.config.update("jax_compilation_cache_dir", None)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir is None

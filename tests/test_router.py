@@ -603,3 +603,33 @@ def test_process_shard_set_matches_inline_router(tenant_data, bounds):
             procs.submit(ev)
         assert procs.drain() == len(list(extra))
         assert procs.stats()["migrations"] == 1
+
+
+def test_process_shard_set_refuses_kernel_drain_while_parent_holds_tpu(
+        tenant_data, bounds, monkeypatch):
+    """A worker cannot reach a chip the parent holds: a pallas drain fails
+    loudly before any worker is asked, and a numpy drain still serves."""
+    shard_host = pytest.importorskip("repro.launch.shard_host")
+    from repro.kernels import _backend
+    lo, hi = bounds
+    fs = make_drift_scenario("sudden_shift", lo, hi, num_tenants=2,
+                             queries_per_tenant=10, seed=9)
+    factories = {f"t{t}": functools.partial(_make_tenant_engine, t)
+                 for t in range(2)}
+    monkeypatch.setattr(_backend, "initialized_platform", lambda: "tpu")
+    with shard_host.ProcessShardSet(factories, num_shards=1) as procs:
+        for ev in fs:
+            procs.submit(ev)
+        with pytest.raises(RuntimeError, match="holds the TPU"):
+            procs.drain(batched=True, compute="pallas_fused")
+        assert procs.drain(batched=True, compute="numpy") == len(list(fs))
+
+
+@pytest.mark.parametrize("platform", [None, "cpu"])
+def test_worker_kernel_drain_allowed_when_parent_holds_no_tpu(platform,
+                                                              monkeypatch):
+    shard_host = pytest.importorskip("repro.launch.shard_host")
+    from repro.kernels import _backend
+    monkeypatch.setattr(_backend, "initialized_platform", lambda: platform)
+    shard_host._check_worker_compute("pallas_fused")
+    shard_host._check_worker_compute("numpy")

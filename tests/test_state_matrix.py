@@ -247,3 +247,32 @@ def test_pallas_fused_compute_backend_parity():
         hi = hi.astype(np.float32).astype(np.float64)
         np.testing.assert_allclose(sm_fu.estimate(lo, hi),
                                    sm_np.estimate(lo, hi), atol=1e-12)
+
+
+@pytest.mark.parametrize("backend", ["pallas", "pallas_fused"])
+def test_state_matrix_f32_guard_warns_and_stays_exact(backend):
+    """A float64 plane under a kernel backend warns and scores through the
+    exact numpy pass, bit for bit."""
+    rng = np.random.default_rng(11)
+    sm_np = StateMatrix()
+    sm_k = StateMatrix(compute_backend=backend)
+    for i, p in enumerate((8, 8, 12)):
+        meta = make_meta(rng, p)
+        sm_np.register(i, meta)
+        sm_k.register(i, meta)
+    lo, hi = make_query(rng)
+    with pytest.warns(RuntimeWarning, match="float32"):
+        got = sm_k.estimate(lo, hi)
+    assert np.array_equal(got, sm_np.estimate(lo, hi))
+
+
+def test_total_rows_rounds_sample_scaled_counts():
+    """Sample-estimated metadata scales counts by a non-integer n/m; a row
+    sum that lands a hair under n must not truncate to n - 1, or a full
+    scan is priced above 1.0 (the TPC-H SF1 row count hit this)."""
+    meta = layouts.PartitionMetadata(mins=np.zeros((2, 1)),
+                                     maxs=np.ones((2, 1)),
+                                     rows=np.array([2.9999999999, 3.0]))
+    assert meta.total_rows == 6
+    full = layouts.eval_cost(meta, np.array([-np.inf]), np.array([np.inf]))
+    assert 0.0 <= full <= 1.0 + 1e-9
