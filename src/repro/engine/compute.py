@@ -26,6 +26,8 @@ from typing import Optional
 
 import numpy as np
 
+from repro import obs
+
 BACKENDS = ("numpy", "pallas", "pallas_fused")
 
 
@@ -50,6 +52,7 @@ def _f32_guard(name: str, *arrays: np.ndarray) -> bool:
     """Warn and return False when a kernel path must fall back to numpy."""
     if float32_exact(*arrays):
         return True
+    obs.count("plane.fallbacks")
     warnings.warn(
         f"{name}: bounds are not exactly float32-representable; the pallas "
         f"kernel's float32 cast would silently change the scan matrix — "
@@ -197,18 +200,36 @@ def fused_frames_scan(q_lo: np.ndarray, q_hi: np.ndarray, minsT: np.ndarray,
     ``FleetMatrix._scanned_all``).  The frame count is padded up to a
     power of two, so passes of varying size compile a handful of kernel
     shapes rather than one per size.
+
+    Traced (:mod:`repro.obs`) as the spans ``plane.stage`` (frame
+    padding), ``plane.upload`` (float32 cast and host-to-device copy of
+    the four operands), ``plane.kernel`` (the launch) and
+    ``plane.readback`` (device compare, wait and copy back), and the
+    counters ``plane.passes``, ``plane.h2d_bytes`` and ``plane.d2h_bytes``.
     """
     import jax.numpy as jnp
 
     from repro.kernels.decision_fused import decision_fused
 
     b = q_lo.shape[0]
-    pad = ((0, (1 << (b - 1).bit_length()) - b), (0, 0), (0, 0))
-    scan, _, _ = decision_fused.fused_decision_pallas(
-        jnp.asarray(np.pad(q_lo, pad), jnp.float32),
-        jnp.asarray(np.pad(q_hi, pad), jnp.float32),
-        jnp.asarray(minsT, jnp.float32), jnp.asarray(maxsT, jnp.float32))
-    return np.asarray(scan[:b] > 0.5)
+    traced = obs.enabled()
+    with obs.span("plane.stage"):
+        pad = ((0, (1 << (b - 1).bit_length()) - b), (0, 0), (0, 0))
+        q_lo, q_hi = np.pad(q_lo, pad), np.pad(q_hi, pad)
+    with obs.span("plane.upload"):
+        operands = [jnp.asarray(a, jnp.float32)
+                    for a in (q_lo, q_hi, minsT, maxsT)]
+    h2d = sum(a.nbytes for a in operands) if traced else 0
+    with obs.span("plane.kernel"):
+        scan, _, _ = decision_fused.fused_decision_pallas(*operands)
+    del operands        # the operands' device buffers go once the kernel ran
+    with obs.span("plane.readback"):
+        out = np.asarray(scan[:b] > 0.5)
+    if traced:
+        obs.count("plane.passes")
+        obs.count("plane.h2d_bytes", h2d)
+        obs.count("plane.d2h_bytes", out.nbytes)
+    return out
 
 
 def _scan_matrix_fused(q_lo, q_hi, mins, maxs) -> np.ndarray:

@@ -42,6 +42,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import layouts as L
 
 from . import compute
@@ -351,10 +352,13 @@ class FleetMatrix:
             # float32, so the plane (checked once per version) and the
             # frame queries must be float32-exact for the bit-identity
             # contract — otherwise fall back to the exact numpy pass.
-            if (self._plane_float32_exact()
-                    and compute.float32_exact(q_lo, q_hi)):
+            with obs.span("plane.stage"):
+                exact = (self._plane_float32_exact()
+                         and compute.float32_exact(q_lo, q_hi))
+            if exact:
                 return compute.fused_frames_scan(q_lo, q_hi,
                                                  self._minsT, self._maxsT)
+            obs.count("plane.fallbacks")
             warnings.warn(
                 "FleetMatrix(pallas_fused): operands are not exactly "
                 "float32-representable; using the exact numpy fused pass",
@@ -407,108 +411,124 @@ class FleetMatrix:
         :attr:`last_pass_dense` — for callers that will consume the pass
         through the bulk decide path and rescore exactly (plane unchanged,
         so bit-identically) in the rare case they cannot.
+
+        Traced (:mod:`repro.obs`) as ``fleet.pass``, holding
+        ``plane.stage`` (query operands, float32 check), the scan's own
+        spans, and ``plane.reduce`` (row-weighted sums, prime tuples).
         """
-        b = len(frames)
-        self.last_pass_dense = None
-        empty: List[List[Optional[tuple]]] = [
-            [None] * len(fr) for fr in frames]
-        if self._t == 0 or self._mins is None or b == 0:
-            return empty
-        tcap, c = self._tcap, self._c
-        # Tenants without a query in a frame get fully-unbounded dummy
-        # bounds: comparisons against +/-inf are identically True, so they
-        # cannot perturb any other tenant's slice and their (unused) output
-        # costs nothing extra to mask.
-        if self._qlo_buf.shape[0] < b * tcap:
-            self._qlo_buf = np.empty((b * tcap, c))
-            self._qhi_buf = np.empty((b * tcap, c))
-        q_lo = self._qlo_buf[:b * tcap]
-        q_hi = self._qhi_buf[:b * tcap]
-        q_lo.fill(-np.inf)
-        q_hi.fill(np.inf)
-        # Per-distinct-tenant facts resolved once per pass, not per event:
-        # (row, n, version, uniform-reduce ok, StateMatrix, shadow slot).
-        info: Dict[str, Optional[tuple]] = {}
-        live: List[Tuple[int, int, tuple]] = []
-        flat: List[int] = []
-        los: List[np.ndarray] = []
-        his: List[np.ndarray] = []
-        for k, items in enumerate(frames):
-            base = k * tcap
-            for j, item in enumerate(items):
-                if len(item) == 2:
-                    tid, query = item
-                    lo, hi = query.lo, query.hi
-                else:
-                    tid, lo, hi = item
-                entry = info.get(tid, False)
-                if entry is False:
-                    row = self._trows.get(tid)
-                    n = len(self._ids[tid]) if row is not None else 0
-                    if row is None or n == 0:
-                        entry = None
+        with obs.span("fleet.pass"):
+            b = len(frames)
+            self.last_pass_dense = None
+            empty: List[List[Optional[tuple]]] = [
+                [None] * len(fr) for fr in frames]
+            if self._t == 0 or self._mins is None or b == 0:
+                return empty
+            with obs.span("plane.stage"):
+                tcap, c = self._tcap, self._c
+                # Tenants without a query in a frame get fully-unbounded
+                # dummy bounds: comparisons against +/-inf are identically
+                # True, so they cannot perturb any other tenant's slice and
+                # their (unused) output costs nothing extra to mask.
+                if self._qlo_buf.shape[0] < b * tcap:
+                    self._qlo_buf = np.empty((b * tcap, c))
+                    self._qhi_buf = np.empty((b * tcap, c))
+                q_lo = self._qlo_buf[:b * tcap]
+                q_hi = self._qhi_buf[:b * tcap]
+                q_lo.fill(-np.inf)
+                q_hi.fill(np.inf)
+                # Per-distinct-tenant facts resolved once per pass, not per
+                # event: (row, n, version, uniform-reduce ok, StateMatrix,
+                # shadow slot).
+                info: Dict[str, Optional[tuple]] = {}
+                live: List[Tuple[int, int, tuple]] = []
+                flat: List[int] = []
+                los: List[np.ndarray] = []
+                his: List[np.ndarray] = []
+                for k, items in enumerate(frames):
+                    base = k * tcap
+                    for j, item in enumerate(items):
+                        if len(item) == 2:
+                            tid, query = item
+                            lo, hi = query.lo, query.hi
+                        else:
+                            tid, lo, hi = item
+                        entry = info.get(tid, False)
+                        if entry is False:
+                            row = self._trows.get(tid)
+                            n = (len(self._ids[tid]) if row is not None
+                                 else 0)
+                            if row is None or n == 0:
+                                entry = None
+                            else:
+                                sm = self._sms[tid]
+                                entry = (row, n, sm.version,
+                                         len(sm) == n and sm.uniform
+                                         and sm.partition_capacity
+                                         == self._pcap,
+                                         sm, self._slots[tid].get(-1))
+                            info[tid] = entry
+                        if entry is None:
+                            continue
+                        flat.append(base + entry[0])
+                        los.append(lo)
+                        his.append(hi)
+                        live.append((k, j, entry))
+                if not live:
+                    return empty
+                idx = np.asarray(flat, dtype=np.intp)
+                q_lo[idx] = np.stack(los)
+                q_hi[idx] = np.stack(his)
+            scanned = self._scanned_all(q_lo.reshape(b, tcap, c),
+                                        q_hi.reshape(b, tcap, c))
+            with obs.span("plane.reduce"):
+                batched: Optional[np.ndarray] = None
+                out = empty
+                if not want_primes:
+                    # Dense-only pass: one batched reduction, no per-event
+                    # tuples.
+                    if any(entry[3] for _, _, entry in live):
+                        batched = (np.einsum("btsp,tsp->bts", scanned,
+                                             self._rows) / self._totals[None])
+                        self.last_pass_dense = (batched, {
+                            tid: (entry[0], entry[1], entry[2], entry[5])
+                            for tid, entry in info.items()
+                            if entry is not None and entry[3]
+                            and entry[5] is not None})
+                    return out
+                for k, j, (row, n, version, fused_ok, sm, shadow) in live:
+                    if fused_ok:
+                        # Equal reduce width and contiguity on both paths:
+                        # the batched (B, T, S, P) einsum accumulates each
+                        # output element exactly like the tenant's own (n, P)
+                        # einsum, so one fused reduction covers every such
+                        # tenant bit-exactly.  (Unequal widths would change
+                        # numpy's accumulator grouping — those tenants take
+                        # the per-tenant reduction below.)
+                        if batched is None:
+                            batched = (np.einsum("btsp,tsp->bts", scanned,
+                                                 self._rows)
+                                       / self._totals[None])
+                        costs = batched[k, row, :n]
+                    elif len(sm) == n:
+                        costs = sm.reduce_scanned(np.ascontiguousarray(
+                            scanned[k, row, :n, :sm.partition_capacity]))
                     else:
-                        sm = self._sms[tid]
-                        entry = (row, n, sm.version,
-                                 len(sm) == n and sm.uniform
-                                 and sm.partition_capacity == self._pcap,
-                                 sm, self._slots[tid].get(-1))
-                    info[tid] = entry
-                if entry is None:
-                    continue
-                flat.append(base + entry[0])
-                los.append(lo)
-                his.append(hi)
-                live.append((k, j, entry))
-        if not live:
-            return empty
-        idx = np.asarray(flat, dtype=np.intp)
-        q_lo[idx] = np.stack(los)
-        q_hi[idx] = np.stack(his)
-        scanned = self._scanned_all(q_lo.reshape(b, tcap, c),
-                                    q_hi.reshape(b, tcap, c))
-        batched: Optional[np.ndarray] = None
-        out = empty
-        if not want_primes:
-            # Dense-only pass: one batched reduction, no per-event tuples.
-            if any(entry[3] for _, _, entry in live):
-                batched = (np.einsum("btsp,tsp->bts", scanned,
-                                     self._rows) / self._totals[None])
-                self.last_pass_dense = (batched, {
-                    tid: (entry[0], entry[1], entry[2], entry[5])
-                    for tid, entry in info.items()
-                    if entry is not None and entry[3]
-                    and entry[5] is not None})
-            return out
-        for k, j, (row, n, version, fused_ok, sm, shadow) in live:
-            if fused_ok:
-                # Equal reduce width and contiguity on both paths: the
-                # batched (B, T, S, P) einsum accumulates each output
-                # element exactly like the tenant's own (n, P) einsum, so
-                # one fused reduction covers every such tenant bit-exactly.
-                # (Unequal widths would change numpy's accumulator grouping
-                # — those tenants take the per-tenant reduction below.)
-                if batched is None:
-                    batched = (np.einsum("btsp,tsp->bts", scanned,
-                                         self._rows) / self._totals[None])
-                costs = batched[k, row, :n]
-            elif len(sm) == n:
-                costs = sm.reduce_scanned(np.ascontiguousarray(
-                    scanned[k, row, :n, :sm.partition_capacity]))
-            else:
-                continue            # plane out of sync mid-churn: fall back
-            # The serving-shadow slot (state id -1), when mirrored, rides
-            # along as a ready-made serve score for backends whose serve()
-            # is the exact shadow estimate (InMemoryBackend, numpy).
-            out[k][j] = (version, costs,
-                         float(costs[shadow]) if shadow is not None else None)
-        if batched is not None:
-            dense_info = {
-                tid: (entry[0], entry[1], entry[2], entry[5])
-                for tid, entry in info.items()
-                if entry is not None and entry[3] and entry[5] is not None}
-            self.last_pass_dense = (batched, dense_info)
-        return out
+                        continue    # plane out of sync mid-churn: fall back
+                    # The serving-shadow slot (state id -1), when mirrored,
+                    # rides along as a ready-made serve score for backends
+                    # whose serve() is the exact shadow estimate
+                    # (InMemoryBackend, numpy).
+                    out[k][j] = (version, costs,
+                                 float(costs[shadow]) if shadow is not None
+                                 else None)
+                if batched is not None:
+                    dense_info = {
+                        tid: (entry[0], entry[1], entry[2], entry[5])
+                        for tid, entry in info.items()
+                        if entry is not None and entry[3]
+                        and entry[5] is not None}
+                    self.last_pass_dense = (batched, dense_info)
+                return out
 
     def estimate_frame(self, items: Sequence[Tuple[str, np.ndarray,
                                                    np.ndarray]],

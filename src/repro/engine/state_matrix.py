@@ -33,6 +33,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core import layouts as L
 
 from . import compute
@@ -261,6 +262,7 @@ class StateMatrix:
                 return compute.fused_frames_scan(
                     q_lo[None, None], q_hi[None, None],
                     self._minsT[:, None], self._maxsT[:, None])[0, 0, :n]
+            obs.count("plane.fallbacks")
             warnings.warn(
                 "StateMatrix(pallas_fused): operands are not exactly "
                 "float32-representable; using the exact numpy pass",

@@ -29,7 +29,10 @@ trace-bitwise interchangeable under the frontend):
 
 All control decisions are clocked by event counters, not wall time, so
 overload behaviour is deterministic and replayable; wall time is only
-*measured* (per-event latency stamps for the benchmark's p50/p99).
+*measured*: per-event latency stamps when ``record_latency`` is set, and,
+while :mod:`repro.obs` is on, the spans ``frontend.pump`` (each pump, the
+root of a request) and ``frontend.queue`` (admission to the start of the
+pump that took the event).
 """
 from __future__ import annotations
 
@@ -38,6 +41,7 @@ import dataclasses
 import time
 from typing import Deque, Dict, Iterable, List, Optional, Tuple
 
+from repro import obs
 from repro.core import workload as wl
 from repro.engine import EventSink
 from repro.engine.fleet import FleetResult
@@ -178,8 +182,10 @@ class FrontendConfig:
     cache_entries: int = 4096
     #: Events dispatched per :meth:`ServeFrontend.pump` call.
     pump_chunk: int = 32
-    #: Stamp wall-clock latency per event (admission → completion).
-    record_latency: bool = True
+    #: Keep wall-clock latency per event (admission → completion) in
+    #: ``ServeFrontend.latencies``.  The admission stamp is also taken
+    #: while the tracer (:mod:`repro.obs`) is on, for ``frontend.queue``.
+    record_latency: bool = False
     #: Route pumps through the fused FleetMatrix pass (run_batched
     #: semantics; the versioned cache is bypassed — the fused pass does
     #: its own serve-score priming).
@@ -259,7 +265,7 @@ class ServeFrontend:
         self._cache = (VersionedResultCache(cfg.cache_entries)
                        if cfg.cache_entries > 0 and not cfg.batched
                        else None)
-        self._queue: Deque[Tuple[wl.Event, Optional[float]]] = \
+        self._queue: Deque[Tuple[wl.Event, Optional[int]]] = \
             collections.deque()
         self._buckets: Dict[str, TokenBucket] = {}
         # (backend, state_matrix) per cache-eligible tenant; None marks a
@@ -303,7 +309,8 @@ class ServeFrontend:
                 return AdmissionResult(False, "queue_full")
             while len(self._queue) >= cfg.queue_capacity:
                 self.pump()
-        t0 = time.perf_counter() if cfg.record_latency else None
+        t0 = (time.perf_counter_ns()
+              if cfg.record_latency or obs.enabled() else None)
         self._queue.append((ev, t0))
         self.admitted += 1
         self._update_breaker()
@@ -330,16 +337,19 @@ class ServeFrontend:
         """Dispatch up to ``max_events`` queued events; returns the count."""
         limit = max_events if max_events is not None else \
             self.config.pump_chunk
-        if self.config.batched:
-            return self._pump_batched(limit)
-        n = 0
-        while self._queue and n < limit:
-            ev, t0 = self._queue.popleft()
-            self._dispatch_one(ev, t0)
-            self.processed += 1
-            n += 1
-            self._update_breaker()
-        return n
+        with obs.span("frontend.pump", root=True) as pump:
+            if self.config.batched:
+                return self._pump_batched(limit, pump.start_ns)
+            n = 0
+            while self._queue and n < limit:
+                ev, t0 = self._queue.popleft()
+                if t0 is not None:
+                    obs.record("frontend.queue", t0, pump.start_ns)
+                self._dispatch_one(ev, t0)
+                self.processed += 1
+                n += 1
+                self._update_breaker()
+            return n
 
     def flush(self) -> int:
         """Pump until the ingress queue is empty; returns events run."""
@@ -359,7 +369,7 @@ class ServeFrontend:
     def result(self, name: Optional[str] = None) -> FleetResult:
         return self.fleet.result(name)
 
-    def _dispatch_one(self, ev: wl.Event, t0: Optional[float]) -> None:
+    def _dispatch_one(self, ev: wl.Event, t0: Optional[int]) -> None:
         cache = self._cache
         fill = None
         if cache is not None and isinstance(ev, wl.QueryEvent):
@@ -384,15 +394,17 @@ class ServeFrontend:
             # version this realized cost may be keyed under.
             cache.put(cache_key(ev.tenant_id, fill.version, ev.query),
                       r.step.query_cost)
-        if t0 is not None:
-            self.latencies.append(time.perf_counter() - t0)
+        if self.config.record_latency and t0 is not None:
+            self.latencies.append((time.perf_counter_ns() - t0) * 1e-9)
 
-    def _pump_batched(self, limit: int) -> int:
+    def _pump_batched(self, limit: int, start_ns: int) -> int:
         cfg = self.config
         n = 0
-        t0s: List[Optional[float]] = []
+        t0s: List[Optional[int]] = []
         while self._queue and n < limit:
             ev, t0 = self._queue.popleft()
+            if t0 is not None:
+                obs.record("frontend.queue", t0, start_ns)
             self.fleet.submit(ev)
             t0s.append(t0)
             n += 1
@@ -401,8 +413,8 @@ class ServeFrontend:
                              frames_per_pass=cfg.frames_per_pass)
             self.processed += n
             if cfg.record_latency:
-                done = time.perf_counter()
-                self.latencies.extend(done - t0 for t0 in t0s
+                done = time.perf_counter_ns()
+                self.latencies.extend((done - t0) * 1e-9 for t0 in t0s
                                       if t0 is not None)
         self._update_breaker()
         return n
