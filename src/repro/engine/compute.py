@@ -201,12 +201,18 @@ def fused_frames_scan(q_lo: np.ndarray, q_hi: np.ndarray, minsT: np.ndarray,
     power of two, so passes of varying size compile a handful of kernel
     shapes rather than one per size.
 
+    ``minsT``/``maxsT`` may instead be float32 device arrays, a plane kept
+    resident on the device (``FleetMatrix``): they go to the kernel as
+    they are, and only the numpy operands are cast and copied up.
+
     Traced (:mod:`repro.obs`) as the spans ``plane.stage`` (frame
     padding), ``plane.upload`` (float32 cast and host-to-device copy of
-    the four operands), ``plane.kernel`` (the launch) and
+    the numpy operands), ``plane.kernel`` (the launch) and
     ``plane.readback`` (device compare, wait and copy back), and the
-    counters ``plane.passes``, ``plane.h2d_bytes`` and ``plane.d2h_bytes``.
+    counters ``plane.passes``, ``plane.h2d_bytes`` (bytes copied up) and
+    ``plane.d2h_bytes``.
     """
+    import jax
     import jax.numpy as jnp
 
     from repro.kernels.decision_fused import decision_fused
@@ -216,10 +222,12 @@ def fused_frames_scan(q_lo: np.ndarray, q_hi: np.ndarray, minsT: np.ndarray,
     with obs.span("plane.stage"):
         pad = ((0, (1 << (b - 1).bit_length()) - b), (0, 0), (0, 0))
         q_lo, q_hi = np.pad(q_lo, pad), np.pad(q_hi, pad)
+    sources = (q_lo, q_hi, minsT, maxsT)
     with obs.span("plane.upload"):
-        operands = [jnp.asarray(a, jnp.float32)
-                    for a in (q_lo, q_hi, minsT, maxsT)]
-    h2d = sum(a.nbytes for a in operands) if traced else 0
+        operands = [a if isinstance(a, jax.Array) else jnp.asarray(
+            a, jnp.float32) for a in sources]
+    h2d = sum(d.nbytes for a, d in zip(sources, operands)
+              if a is not d) if traced else 0
     with obs.span("plane.kernel"):
         scan, _, _ = decision_fused.fused_decision_pallas(*operands)
     del operands        # the operands' device buffers go once the kernel ran

@@ -24,6 +24,10 @@ tick**:
 * capacity growth (more tenants, more states, wider partitions) is
   geometric and amortized.
 
+Under ``pallas_fused`` a float32 copy of the transposed plane stays
+resident on the device; it is rebuilt whole on the first pass after
+:attr:`FleetMatrix.version` moves, so a pass uploads only its queries.
+
 Bit-identity contract (numpy path): for each tenant, the fused fleet scan
 restricted to that tenant's ``(n, P_cap_local)`` window equals the
 booleans its own plane would compute — padded slots carry ``[+inf, -inf]``
@@ -70,6 +74,9 @@ class FleetMatrix:
 
     def __init__(self, compute_backend: str = "numpy",
                  tenant_capacity: int = 4, state_capacity: int = 8):
+        # (version, minsT, maxsT): float32 device copies of the transposed
+        # plane for the pallas_fused pass, rebuilt when version moves.
+        self._device_plane: Optional[tuple] = None
         self.set_compute_backend(compute_backend)
         self._tcap = max(int(tenant_capacity), 1)
         self._scap = max(int(state_capacity), 1)
@@ -116,6 +123,8 @@ class FleetMatrix:
         """Switch the fused-scan compute path (validated; tensors shared)."""
         if compute_backend not in compute.BACKENDS:
             raise ValueError(f"unknown compute backend: {compute_backend!r}")
+        if compute_backend != "pallas_fused":
+            self._device_plane = None   # only the fused pass keeps one
         self.compute_backend = compute_backend
 
     # -- introspection --------------------------------------------------
@@ -268,6 +277,7 @@ class FleetMatrix:
     def detach_all(self) -> None:
         for tid in list(self._tids):
             self.detach(tid)
+        self._device_plane = None
 
     # -- per-state maintenance (O(P*C) per event) -----------------------
     def _register(self, tid: str, state_id: int,
@@ -357,7 +367,7 @@ class FleetMatrix:
                          and compute.float32_exact(q_lo, q_hi))
             if exact:
                 return compute.fused_frames_scan(q_lo, q_hi,
-                                                 self._minsT, self._maxsT)
+                                                 *self._resident_plane())
             obs.count("plane.fallbacks")
             warnings.warn(
                 "FleetMatrix(pallas_fused): operands are not exactly "
@@ -375,6 +385,26 @@ class FleetMatrix:
             return np.stack(frames)
         return compute.fleet_masked_overlap(self._minsT, self._maxsT,
                                             q_lo, q_hi)
+
+    def _resident_plane(self) -> tuple:
+        """float32 device copies of ``(minsT, maxsT)``, rebuilt only when
+        :attr:`version` has moved since the last build (every write to the
+        host twins bumps it).
+
+        Traced (:mod:`repro.obs`) as the span ``plane.refresh`` and the
+        counters ``plane.refreshes`` and ``plane.h2d_bytes``.
+        """
+        cached = self._device_plane
+        if cached is None or cached[0] != self.version:
+            import jax.numpy as jnp
+            self._device_plane = None       # free the stale pair first
+            with obs.span("plane.refresh"):
+                planes = tuple(jnp.asarray(a, jnp.float32)
+                               for a in (self._minsT, self._maxsT))
+            obs.count("plane.refreshes")
+            obs.count("plane.h2d_bytes", sum(a.nbytes for a in planes))
+            cached = self._device_plane = (self.version,) + planes
+        return cached[1:]
 
     def _plane_float32_exact(self) -> bool:
         """Cached-per-version float32-representability of the packed plane."""

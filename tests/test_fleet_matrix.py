@@ -645,3 +645,150 @@ def test_bulk_path_runs_megakernel_on_f32_exact_data(monkeypatch):
         assert np.array_equal(a.query_costs, b.query_costs)
         assert a.reorg_indices == b.reorg_indices
         assert np.array_equal(a.state_seq, b.state_seq)
+
+
+# ---------------------------------------------------------------------------
+# Device-resident plane (pallas_fused): rebuilt once per plane version
+# ---------------------------------------------------------------------------
+
+def f32(a):
+    return a.astype(np.float32).astype(np.float64)
+
+
+def f32_meta(rng, partitions, columns, rows_per=50):
+    data = f32(rng.uniform(0, 100, size=(partitions * rows_per, columns)))
+    assignment = np.repeat(np.arange(partitions), rows_per)
+    return layouts.metadata_from_assignment(data, assignment, partitions)
+
+
+@pytest.fixture
+def tracer():
+    from repro import obs
+    obs.reset()
+    obs.enable()
+    yield obs
+    obs.disable()
+    obs.reset()
+
+
+def resident_fleet(rng, backend="pallas_fused"):
+    """Tenants a, b with two float32-exact states of 4 partitions each, a
+    fused fleet with room for one more tenant and two more states, and a
+    numpy twin attached to the same tenant planes."""
+    sms = {}
+    for tid in "ab":
+        sms[tid] = StateMatrix()
+        for sid in range(2):
+            sms[tid].register(sid, f32_meta(rng, 4, 3))
+    fused = FleetMatrix(backend, tenant_capacity=3, state_capacity=4)
+    exact = FleetMatrix("numpy", tenant_capacity=3, state_capacity=4)
+    for tid, sm in sms.items():
+        fused.attach(tid, sm)
+        exact.attach(tid, sm)
+    return fused, exact, sms
+
+
+def f32_frames(rng, tids, n=2):
+    frames = []
+    for _ in range(n):
+        frame = []
+        for tid in tids:
+            lo, hi = make_query(rng, 3, bounded=2)
+            frame.append((tid, f32(lo), f32(hi)))
+        frames.append(frame)
+    return frames
+
+
+def new_tenant(fleets, sms, rng, tid, states=2):
+    sms[tid] = StateMatrix()
+    for sid in range(states):
+        sms[tid].register(sid, f32_meta(rng, 4, 3))
+    for fm in fleets:
+        fm.attach(tid, sms[tid])
+
+
+def detach(fleets, tid):
+    for fm in fleets:
+        fm.detach(tid)
+
+
+CHURN = {
+    "attach": lambda f, s, r: [lambda: new_tenant(f, s, r, "c")],
+    "register": lambda f, s, r: [
+        lambda: s["a"].register(2, f32_meta(r, 4, 3))],
+    "deregister_swap_with_last": lambda f, s, r: [
+        lambda: s["a"].deregister(0)],
+    "serving_shadow_reregister": lambda f, s, r: [
+        lambda: s["b"].register(-1, f32_meta(r, 4, 3)),
+        lambda: s["b"].register(-1, f32_meta(r, 4, 3))],
+    "grow_tenants": lambda f, s, r: [
+        lambda: new_tenant(f, s, r, "c"), lambda: new_tenant(f, s, r, "d")],
+    "grow_states": lambda f, s, r: [
+        lambda: s["a"].register(2, f32_meta(r, 4, 3)),
+        lambda: s["a"].register(3, f32_meta(r, 4, 3)),
+        lambda: s["a"].register(4, f32_meta(r, 4, 3))],
+    "grow_partitions": lambda f, s, r: [
+        lambda: s["b"].register(2, f32_meta(r, 9, 3))],
+    "detach": lambda f, s, r: [lambda: detach(f, "a")],
+}
+
+
+@pytest.mark.parametrize("churn", sorted(CHURN))
+def test_resident_plane_refreshes_once_per_mutation_and_stays_exact(
+        churn, tracer):
+    """Every plane mutation gives exactly one rebuild of the device plane,
+    at the next pass, and none on later passes at the same version; each
+    pass equals the numpy pass bit for bit, and the device plane holds the
+    host twins' float32 values."""
+    rng = np.random.default_rng(31)
+    fused, exact, sms = resident_fleet(rng)
+    steps = [lambda: None] + CHURN[churn]((fused, exact), sms, rng)
+    refreshes = 0
+    for step in steps:
+        step()
+        for _ in range(3):
+            frames = f32_frames(rng, fused.tenant_ids)
+            got = fused.estimate_frames(frames)
+            want = exact.estimate_frames(frames)
+            for g_frame, w_frame in zip(got, want):
+                for g, w in zip(g_frame, w_frame):
+                    assert g[0] == w[0] and g[2] == w[2]
+                    assert np.array_equal(g[1], w[1])       # bitwise
+        counters = tracer.snapshot()["counters"]
+        assert counters["plane.refreshes"] == refreshes + 1
+        refreshes += 1
+        version, minsT, maxsT = fused._device_plane
+        assert version == fused.version
+        assert minsT.dtype == maxsT.dtype == np.float32
+        assert np.array_equal(np.asarray(minsT),
+                              fused._minsT.astype(np.float32))
+        assert np.array_equal(np.asarray(maxsT),
+                              fused._maxsT.astype(np.float32))
+    assert "plane.fallbacks" not in counters
+    assert counters["plane.passes"] == 3 * len(steps)
+
+
+@pytest.mark.parametrize("case", ["numpy", "pallas", "switch_to_numpy",
+                                  "switch_to_pallas", "detach_all",
+                                  "same_backend_keeps"])
+def test_only_the_fused_pass_holds_a_device_plane(case, tracer):
+    rng = np.random.default_rng(37)
+    backend = case if case in ("numpy", "pallas") else "pallas_fused"
+    fm, _, _ = resident_fleet(rng, backend)
+    fm.estimate_frames(f32_frames(rng, fm.tenant_ids, n=1))
+    built = fm._device_plane
+    assert (built is None) == (backend != "pallas_fused")
+    if case.startswith("switch_to_"):
+        fm.set_compute_backend(case[len("switch_to_"):])
+        assert fm._device_plane is None
+        fm.estimate_frames(f32_frames(rng, fm.tenant_ids, n=1))
+        assert fm._device_plane is None
+    elif case == "detach_all":
+        fm.detach_all()
+        assert fm._device_plane is None
+    elif case == "same_backend_keeps":
+        fm.set_compute_backend("pallas_fused")
+        fm.estimate_frames(f32_frames(rng, fm.tenant_ids, n=1))
+        assert fm._device_plane is built
+    counters = tracer.snapshot()["counters"]
+    assert counters.get("plane.refreshes", 0) == (backend == "pallas_fused")

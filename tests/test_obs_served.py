@@ -83,15 +83,21 @@ def untraced():
 
 @pytest.fixture(scope="module")
 def traced():
-    """A traced run, with every fused pass's operand shapes and every
-    kernel launch seen from outside the program."""
-    shapes, launches = [], []
+    """A traced run, with every fused pass's operands, every kernel launch
+    and the plane version of every fleet pass seen from outside the
+    program."""
+    shapes, launches, versions = [], [], []
     scan, kernel = compute.fused_frames_scan, \
         decision_fused.fused_decision_pallas
+    scanned_all = FleetMatrix._scanned_all
 
     def frames_scan(q_lo, q_hi, minsT, maxsT):
-        shapes.append((q_lo.shape, minsT.shape))
+        shapes.append((q_lo.shape, minsT))
         return scan(q_lo, q_hi, minsT, maxsT)
+
+    def fleet_pass(self, q_lo, q_hi):
+        versions.append(self.version)
+        return scanned_all(self, q_lo, q_hi)
 
     def launch(*args, **kwargs):
         launches.append(1)
@@ -99,6 +105,7 @@ def traced():
 
     compute.fused_frames_scan = frames_scan
     decision_fused.fused_decision_pallas = launch
+    FleetMatrix._scanned_all = fleet_pass
     fe = frontend(tables(exact=True))
     obs.reset()
     obs.enable()
@@ -108,9 +115,10 @@ def traced():
         obs.disable()
         compute.fused_frames_scan = scan
         decision_fused.fused_decision_pallas = kernel
+        FleetMatrix._scanned_all = scanned_all
     snap = obs.snapshot()
     obs.reset()
-    return result, snap, shapes, launches, fe
+    return result, snap, shapes, launches, fe, versions
 
 
 def test_results_are_bit_identical_with_tracing_on_and_off(untraced,
@@ -126,23 +134,36 @@ def test_results_are_bit_identical_with_tracing_on_and_off(untraced,
 
 
 def test_pass_counters_match_the_kernel_launches(traced):
-    _, snap, shapes, launches, _ = traced
+    """The queries go up on every pass; a tenant's own plane goes up with
+    its pass, the fleet's resident plane once for each plane version its
+    passes scored."""
+    _, snap, shapes, launches, _, versions = traced
     counters = snap["counters"]
     assert launches and counters["plane.passes"] == len(launches)
     assert len(shapes) == len(launches)
     assert "plane.fallbacks" not in counters
     h2d = d2h = 0
-    for (b, t, c), (c2, t2, s, p) in shapes:
+    resident = {}
+    for (b, t, c), plane in shapes:
+        c2, t2, s, p = plane.shape
         assert (c, t) == (c2, t2)
         b_pad = 1 << (b - 1).bit_length()
-        h2d += 2 * 4 * b_pad * t * c + 2 * 4 * c * t * s * p
+        h2d += 2 * 4 * b_pad * t * c
+        if isinstance(plane, np.ndarray):
+            h2d += 2 * 4 * c * t * s * p
+        else:
+            resident[id(plane)] = plane
         d2h += b * t * s * p                    # one bool per partition
+    h2d += sum(2 * 4 * plane.size for plane in resident.values())
+    assert versions and len(set(versions)) < len(versions)
+    assert counters["plane.refreshes"] == len(set(versions)) \
+        == len(resident)
     assert counters["plane.h2d_bytes"] == h2d
     assert counters["plane.d2h_bytes"] == d2h
 
 
 def test_the_plane_spans_nest_under_the_fleet_pass(traced):
-    _, snap, _, launches, _ = traced
+    _, snap, _, launches, _, _ = traced
     recs = snap["records"]
     by_id = {r["id"]: r for r in recs if r["id"] is not None}
     spans = snap["spans"]
