@@ -14,7 +14,9 @@ draws the deployment states, and with nothing imported from the program:
   ``gamma``, stay at phase start) picks the decision state of every query
   from the estimated costs of all known states;
 * a charged reorganization (cost ``alpha``) swaps the serving layout
-  ``delta`` queries later, rewriting the whole table (exact zone maps);
+  ``delta`` queries later, rewriting the whole table (exact zone maps) --
+  or, under ``reorg: "incremental"``, begins there a migration that moves
+  the table a few partitions a step (:class:`Migrations`);
 * each query is served at the fraction of rows in the partitions of the
   serving layout whose zone maps overlap it.
 
@@ -28,6 +30,8 @@ from __future__ import annotations
 
 import dataclasses
 import heapq
+import math
+from fractions import Fraction
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -52,19 +56,32 @@ def zone_maps(data: np.ndarray, assign: np.ndarray, k: int,
               row_scale: float = 1.0) -> Meta:
     """Per-partition min, max and (scaled) row count; empty partitions keep
     ``[+inf, -inf]`` and 0 rows."""
-    c = data.shape[1]
-    mins = np.full((k, c), np.inf)
-    maxs = np.full((k, c), -np.inf)
     order = np.argsort(assign, kind="stable")
     bounds = np.searchsorted(assign[order], np.arange(k + 1))
-    starts, ends = bounds[:-1], bounds[1:]
+    meta = run_maps(np.ascontiguousarray(data[order].T), bounds[:-1],
+                    bounds[1:])
+    meta.rows *= row_scale
+    return meta
+
+
+def run_maps(columns: np.ndarray, starts: np.ndarray, ends: np.ndarray
+             ) -> Meta:
+    """Zone maps of the row runs ``[starts[k], ends[k])`` of ``columns``
+    (C, N), rows along the second axis; the runs lie in order and do not
+    overlap, and an empty run keeps ``[+inf, -inf]`` and 0 rows."""
+    k, c = len(starts), columns.shape[0]
+    mins = np.full((k, c), np.inf)
+    maxs = np.full((k, c), -np.inf)
     full = ends > starts
-    grouped = data[order]
-    mins[full] = np.minimum.reduceat(grouped, starts[full], axis=0)
-    maxs[full] = np.maximum.reduceat(grouped, starts[full], axis=0)
-    rows = np.zeros(k)
-    rows[full] = (ends[full] - starts[full]) * row_scale
-    return Meta(mins, maxs, rows)
+    if full.any():
+        # Reduce over [start, end) and [end, next start) alike; keep the
+        # first of each pair.
+        cuts = np.stack([starts[full], ends[full]], axis=1).ravel()
+        if cuts[-1] == columns.shape[1]:
+            cuts = cuts[:-1]
+        mins[full] = np.minimum.reduceat(columns, cuts, axis=1)[:, ::2].T
+        maxs[full] = np.maximum.reduceat(columns, cuts, axis=1)[:, ::2].T
+    return Meta(mins, maxs, (ends - starts).astype(np.float64))
 
 
 class Costing:
@@ -332,6 +349,189 @@ class DUMTS:
         self.moves += 1
 
 
+# -- incremental reorganization --------------------------------------------
+
+def closing_charges(charged: float, alpha: float) -> List[float]:
+    """The last charges of a migration, whose left-to-right float sum
+    after ``charged`` is ``alpha`` to the bit.
+
+    One charge where a float closes the sum: of the two floats next to
+    the exact remainder ``alpha - charged``, the nearer one that closes.
+    Where neither does, the term is split in two: the nearer float, then
+    what is left, which is exact because the sum is then within a few
+    units in the last place of ``alpha``."""
+    exact = Fraction(alpha) - Fraction(charged)
+    near = alpha - charged
+    away = math.nextafter(near, math.inf if Fraction(near) < exact
+                          else -math.inf)
+    for inc in (near, away):
+        if charged + inc == alpha:
+            return [inc]
+    return [near, alpha - (charged + near)]
+
+
+@dataclasses.dataclass
+class Migration:
+    """One migration from the serving layout to ``target``.
+
+    ``moves`` are the target partitions that move, in move order, and
+    ``move_rows`` their rows.  The table's rows are sorted once by
+    (source partition, rank of their target partition's move), so that at
+    any step the rows a source partition still holds are one contiguous
+    run at the end of its rows."""
+
+    target: Layout
+    moves: List[int]
+    move_rows: List[int]
+    num_sources: int
+    columns: np.ndarray             # (C, N) rows sorted by (source, rank)
+    keys: np.ndarray                # their keys, source * (M + 1) + rank
+    done_at: np.ndarray             # target partition -> move rank (or M)
+    done: int = 0                   # moves completed (a prefix)
+    bank: int = 0                   # rows granted and not yet spent
+    moved: int = 0
+    charged: float = 0.0
+
+    @property
+    def total(self) -> int:
+        return sum(self.move_rows)
+
+    def hybrid(self, data: np.ndarray) -> Meta:
+        """Zone maps of the table as it lies now: one residual partition
+        per source partition, holding its rows not yet moved, then one per
+        target partition, holding its rows once its move is done."""
+        m = len(self.moves)
+        src = np.arange(self.num_sources) * (m + 1)
+        res = run_maps(self.columns,
+                       np.searchsorted(self.keys, src + self.done),
+                       np.searchsorted(self.keys, src + m + 1))
+        tgt = self.target.materialize(data)
+        moved = (self.done_at < self.done)[:, None]
+        return Meta(np.concatenate([res.mins,
+                                    np.where(moved, tgt.mins, np.inf)]),
+                    np.concatenate([res.maxs,
+                                    np.where(moved, tgt.maxs, -np.inf)]),
+                    np.concatenate([res.rows,
+                                    np.where(moved[:, 0], tgt.rows, 0.0)]))
+
+
+class Migrations:
+    """Incremental reorganization (``reorg: "incremental"``), one tenant.
+
+    * Charged swaps fall due ``delta`` queries after their charge and
+      begin in charge order, one migration in flight at a time; a due swap
+      waits behind the one in flight.  A swap whose state was evicted
+      before it begins is skipped.
+    * A migration moves the layout diff: every non-empty target partition
+      except those identical to a source partition (all their rows come
+      from one source partition that sends rows nowhere else).  Moves are
+      ordered by (-benefit per row, target partition), the benefit of a
+      move being the scans it saves per row over the last ``window``
+      queries (the current one included): its rows leave source
+      partitions read at their scan frequency for a target partition read
+      at its own.
+    * Each step grants up to ``rows_per_tick`` rows, banked: a move
+      completes once the bank covers its rows.  When a migration
+      completes, the step's unspent rows go to the next due swap, which
+      begins in the same step.
+    * Until it completes, queries are served on the hybrid table
+      (:meth:`Migration.hybrid`); then on the target's exact zone maps.
+    * A step that completes moves charges ``alpha * (moved / total) -
+      charged``; the completing step closes the migration's ledger on
+      ``alpha`` (:func:`closing_charges`).
+
+    The frequencies are exact (the program's ``reorg_compute="numpy"``),
+    and every row asked for is granted (the fleet's ``unlimited``
+    scheduler): the reference holds under those two only.
+    """
+
+    def __init__(self, data: np.ndarray, costing: Costing, alpha: float,
+                 rows_per_tick: int, window: int, serving: Layout):
+        self.data, self.costing, self.alpha = data, costing, alpha
+        self.rows_per_tick, self.window = rows_per_tick, window
+        self.layout = serving           # what the table is laid out as
+        self.active: Optional[Migration] = None
+        #: (index, rows, charge) of every migration step that moved rows,
+        #: migrations in begin order.
+        self.ledger: list = []
+
+    def step(self, i: int, pending: List[Tuple[int, int]],
+             known: Dict[int, Layout], recent: Sequence[Tuple[np.ndarray,
+                                                               np.ndarray]],
+             serving: Meta) -> Meta:
+        """Spend step ``i``'s rows; returns the zone maps to serve on."""
+        room = self.rows_per_tick
+        while True:
+            if self.active is not None:
+                room, serving = self._advance(i, room, serving)
+                if self.active is not None:
+                    return serving
+            if not (pending and pending[0][0] <= i):
+                return serving
+            _, sid = pending.pop(0)
+            if sid in known:
+                self.active = self._plan(known[sid], recent)
+
+    def _plan(self, target: Layout, recent) -> Migration:
+        data, costing = self.data, self.costing
+        src_meta = self.layout.materialize(data)
+        tgt_meta = target.materialize(data)
+        p_s, p_t = src_meta.mins.shape[0], tgt_meta.mins.shape[0]
+        a_s = self.layout.route(data)
+        a_t = target.route(data)
+        block = np.bincount(a_s * p_t + a_t, minlength=p_s * p_t
+                            ).reshape(p_s, p_t).astype(np.float64)
+        src_rows, tgt_rows = block.sum(axis=1), block.sum(axis=0)
+        feeds = block > 0
+        feeder = feeds.argmax(axis=0)
+        identical = ((feeds.sum(axis=0) == 1)
+                     & (block[feeder, np.arange(p_t)] == src_rows[feeder]))
+        diff = [j for j in range(p_t) if tgt_rows[j] > 0 and not identical[j]]
+        q_lo = np.stack([q[0] for q in recent])
+        q_hi = np.stack([q[1] for q in recent])
+        f_src = costing.scanned(src_meta, q_lo, q_hi).mean(axis=0)
+        f_tgt = costing.scanned(tgt_meta, q_lo, q_hi).mean(axis=0)
+        # Moves whose float64 benefits tie keep target order.
+        gain = block.T @ f_src - tgt_rows * f_tgt
+        benefit = np.divide(gain, tgt_rows, out=np.zeros(p_t),
+                            where=tgt_rows > 0)
+        moves = sorted(diff, key=lambda j: (-benefit[j], j))
+        done_at = np.full(p_t, len(moves))
+        done_at[moves] = np.arange(len(moves))
+        keys = a_s * (len(moves) + 1) + done_at[a_t]
+        order = np.argsort(keys, kind="stable")
+        return Migration(target, moves, [int(tgt_rows[j]) for j in moves],
+                         p_s, np.ascontiguousarray(data[order].T),
+                         keys[order], done_at)
+
+    def _advance(self, i: int, room: int, serving: Meta
+                 ) -> Tuple[int, Meta]:
+        mig = self.active
+        want = max(min(sum(mig.move_rows[mig.done:]) - mig.bank, room), 0)
+        mig.bank += want
+        rows = 0
+        start = mig.done
+        while (mig.done < len(mig.moves)
+               and mig.bank >= mig.move_rows[mig.done]):
+            mig.bank -= mig.move_rows[mig.done]
+            rows += mig.move_rows[mig.done]
+            mig.done += 1
+        if mig.done == start and mig.done < len(mig.moves):
+            return room - want, serving
+        mig.moved += rows
+        if mig.done == len(mig.moves):
+            charges = closing_charges(mig.charged, self.alpha)
+            self.ledger.append((i, rows, charges[0]))
+            self.ledger += [(i, 0, c) for c in charges[1:]]
+            self.layout = mig.target
+            self.active = None
+            return room - want, mig.target.materialize(self.data)
+        inc = self.alpha * (mig.moved / max(mig.total, 1)) - mig.charged
+        mig.charged = mig.charged + inc
+        self.ledger.append((i, rows, inc))
+        return room - want, mig.hybrid(self.data)
+
+
 @dataclasses.dataclass
 class Trace:
     """What one tenant's run produced, per query in service order."""
@@ -339,18 +539,23 @@ class Trace:
     costs: np.ndarray               # served cost (fraction of rows read)
     states: np.ndarray              # decision state
     reorgs: List[int]               # query indices charged alpha
-    alpha_ledger: list              # (index, charge) entries, in order
+    alpha_ledger: list              # see run_tenant
 
 
 def run_tenant(data: np.ndarray, queries: Sequence[Tuple[np.ndarray,
                                                          np.ndarray]],
                cfg: dict, seed: int, precision: str = "float32") -> Trace:
     """Replay one tenant's queries through the OReO loop of ``cfg`` (the
-    deployment's configuration file); ``seed`` seeds its draws.  The
-    serving layout is swapped whole at the due step (``reorg: "atomic"``);
-    the α ledger holds one ``(index, alpha)`` entry per reorganization.
+    deployment's configuration file); ``seed`` seeds its draws.
+
+    ``reorg: "atomic"``: the serving layout is swapped whole at the due
+    step, and the α ledger holds one ``(index, alpha)`` entry per
+    reorganization.  ``reorg: "incremental"``: the due step begins a
+    migration of at most ``ceil(rows / migration_steps)`` rows a step,
+    ordered over the last ``reorg_window`` queries (:class:`Migrations`),
+    and the α ledger holds its ``(index, rows, charge)`` steps.
     """
-    if cfg["reorg"] != "atomic":
+    if cfg["reorg"] not in ("atomic", "incremental"):
         raise ValueError(f"unsupported reorg mode {cfg['reorg']!r}")
     costing = Costing(precision)
     mgr = cfg["manager"]
@@ -366,6 +571,11 @@ def run_tenant(data: np.ndarray, queries: Sequence[Tuple[np.ndarray,
     serving = initial.materialize(data)
     pending: List[Tuple[int, int]] = []
     costs, states, reorgs = [], [], []
+    migrations = None
+    if cfg["reorg"] == "incremental":
+        migrations = Migrations(data, costing, alpha,
+                                -(-len(data) // cfg["migration_steps"]),
+                                cfg["reorg_window"], initial)
 
     def vectors(layouts: Dict[int, Layout]) -> Dict[int, np.ndarray]:
         """Cost vectors over the R-TBS sample (empty while it is)."""
@@ -433,14 +643,20 @@ def run_tenant(data: np.ndarray, queries: Sequence[Tuple[np.ndarray,
             reorgs.append(i)
             pending.append((i + cfg["delta"], state))
         # Delta-delayed swaps, then serve.
-        while pending and pending[0][0] <= i:
-            _, sid = pending.pop(0)
-            if sid in known:
-                serving = known[sid].materialize(data)
+        if migrations is None:
+            while pending and pending[0][0] <= i:
+                _, sid = pending.pop(0)
+                if sid in known:
+                    serving = known[sid].materialize(data)
+        else:
+            recent = queries[max(0, i + 1 - migrations.window):i + 1]
+            serving = migrations.step(i, pending, known, recent, serving)
         costs.append(costing.cost(serving, lo, hi))
         states.append(state)
+    ledger = ([(i, alpha) for i in reorgs] if migrations is None
+              else migrations.ledger)
     return Trace(np.asarray(costs), np.asarray(states, dtype=np.int64),
-                 reorgs, [(i, alpha) for i in reorgs])
+                 reorgs, ledger)
 
 
 def replay(job: dict) -> Trace:

@@ -133,11 +133,18 @@ def warmup_per_tenant(cfg: dict) -> int:
     at which the window is at least half full), then two Δ-delays more.
     The first candidate replaces the arrival-order layout, which every
     query scans whole, within a few queries, and its rewrite lands δ
-    queries after the charge; so set-up, not the window, pays for it."""
+    queries after the charge; so set-up, not the window, pays for it.
+    Under incremental reorganization that rewrite is a migration of up to
+    ``migration_steps`` steps, and the warm-up takes one step more than
+    that, so the migration completes in set-up and its hybrid planes are
+    scored, and their kernel shapes compiled, there."""
     mgr = cfg["manager"]
     half = mgr["window_size"] // 2
-    return (mgr["gen_every"] * max(1, -(-half // mgr["gen_every"]))
-            + 2 * cfg["delta"])
+    n = (mgr["gen_every"] * max(1, -(-half // mgr["gen_every"]))
+         + 2 * cfg["delta"])
+    if cfg["reorg"] == "incremental":
+        n += cfg["migration_steps"] + 1
+    return n
 
 
 # -- the run record the metric readers see --------------------------------
@@ -288,9 +295,10 @@ def compare(got: dict, want: Dict[str, reference.Trace], unserved: int,
       reference's, over every tenant and query;
     * ``state_mismatches``: queries whose decision state differs;
     * ``reorg_mismatches``: reorganizations charged at one side only;
-    * ``ledger_mismatches``: entries of the α ledgers (one per
-      reorganization: index and amount) that differ, or are on one side
-      only;
+    * ``ledger_mismatches``: entries of the α ledgers that differ, or are
+      on one side only: one per reorganization (index, amount) if atomic,
+      one per migration step that moved rows (index, rows, amount) if
+      incremental;
     * ``unserved``: events due in the window that were never served.
 
     A tenant whose trace is shorter on one side counts every missing query
@@ -322,10 +330,15 @@ def compare(got: dict, want: Dict[str, reference.Trace], unserved: int,
 def window_counts(before: dict, after: dict, cfg: dict
                   ) -> Dict[str, Dict[str, int]]:
     """Per tenant, what the window's mechanism did: candidate builds,
-    reorganizations charged and swaps (table rewrites) falling due."""
+    reorganizations charged and swaps (table rewrites, or migrations)
+    falling due; under incremental reorganization also the migration
+    steps that moved rows and charged their share of α."""
     out: Dict[str, Dict[str, int]] = {
         "candidate_builds": {}, "reorganizations_charged": {},
         "swaps_due": {}}
+    incremental = cfg["reorg"] == "incremental"
+    if incremental:
+        out["migration_steps_charged"] = {}
     for tid in after:
         lo, hi = len(before[tid].costs), len(after[tid].costs)
         a = after[tid]
@@ -334,6 +347,9 @@ def window_counts(before: dict, after: dict, cfg: dict
             lo <= r < hi for r in a.reorgs)
         out["swaps_due"][tid] = sum(lo <= r + cfg["delta"] < hi
                                     for r in a.reorgs)
+        if incremental:
+            out["migration_steps_charged"][tid] = sum(
+                lo <= entry[0] < hi for entry in a.alpha_ledger)
     return out
 
 

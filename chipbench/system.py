@@ -26,8 +26,15 @@ COMPUTE = "pallas_fused"
 def make_engines(tables: Dict[str, np.ndarray], cfg: dict,
                  compute: str = COMPUTE) -> Dict[str, LayoutEngine]:
     """One engine per tenant; tenant ``k``'s draws are seeded with ``k``.
-    Reorganization is atomic: a swap rewrites the whole table."""
-    if cfg["reorg"] != "atomic":
+
+    ``reorg: "atomic"``: a swap rewrites the whole table.  ``reorg:
+    "incremental"``: a swap begins a migration that moves at most
+    ``ceil(rows / migration_steps)`` rows a step, its moves ordered over the
+    last ``reorg_window`` queries by the program's exact numpy scan
+    frequencies.  The per-tenant reference holds only under those
+    frequencies and the router's default ``unlimited`` scheduler, which
+    grants every row a migration asks for."""
+    if cfg["reorg"] not in ("atomic", "incremental"):
         raise ValueError(f"unsupported reorg mode {cfg['reorg']!r}")
     engines = {}
     mgr = cfg["manager"]
@@ -42,8 +49,15 @@ def make_engines(tables: Dict[str, np.ndarray], cfg: dict,
         policy = OreoPolicy(data, build_default_layout(0, data,
                                                        cfg["partitions"]),
                             make_generator("qdtree", seed=k), oreo)
+        incremental = {}
+        if cfg["reorg"] == "incremental":
+            incremental = dict(
+                incremental=True,
+                rows_per_tick=-(-len(data) // cfg["migration_steps"]),
+                reorg_window=cfg["reorg_window"], reorg_compute="numpy")
         engines[tid] = LayoutEngine(
-            policy, InMemoryBackend(data, compute=compute), delta=cfg["delta"])
+            policy, InMemoryBackend(data, compute=compute), delta=cfg["delta"],
+            **incremental)
     return engines
 
 
@@ -68,20 +82,29 @@ class TenantTrace:
 
     costs: np.ndarray
     states: np.ndarray
-    reorgs: List[int]
-    alpha_ledger: list              # (index, charge) entries, in order
+    reorgs: List[int]               # query indices charged alpha
+    alpha_ledger: list              # see traces()
     builds: int
 
 
 def traces(frontend: ServeFrontend) -> Dict[str, TenantTrace]:
+    """Per tenant, what the program produced.  The α ledger is one
+    ``(index, alpha)`` entry per charged reorganization (atomic), or every
+    migration's ``(index, rows, charge)`` steps in begin order
+    (incremental)."""
     out = {}
     for tid, r in frontend.result().per_tenant.items():
         engine = frontend.fleet.tenant(tid)
+        executor = engine.reorg_executor
+        if executor is None:
+            ledger = [(i, engine.alpha) for i in r.reorg_indices]
+        else:
+            ledger = [c for m in executor.migrations for c in m.charges]
         out[tid] = TenantTrace(
             costs=np.asarray(r.query_costs, dtype=np.float64),
             states=np.asarray(r.state_seq, dtype=np.int64),
             reorgs=list(r.reorg_indices),
-            alpha_ledger=[(i, engine.alpha) for i in r.reorg_indices],
+            alpha_ledger=ledger,
             builds=int(r.info.get("candidates_generated", 0)))
     return out
 
