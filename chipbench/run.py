@@ -24,8 +24,9 @@ One run:
    wait, so an event that falls due during a long pump waits behind it.
    Each event is timed from when it was due to when the pump that served
    it returned; the window ends when the last due event has been served.
-   With ``--trace 1`` the window is traced by the JAX profiler and the
-   harness's host spans are on.
+   With ``--trace 1`` the window is traced by the JAX profiler, and the
+   harness's host spans and the program's own tracer (``repro.obs``) are
+   on; with ``--trace 0`` neither is.
 3. The check: every event served, warm-up and window, is compared with a
    plain reference (``reference.py``) replaying each tenant's queries;
    each number compared is printed beside its limit.
@@ -162,6 +163,19 @@ class Run:
     spans: object = None            # probes.Spans, traced runs only
     launches: list = dataclasses.field(default_factory=list)  # probes.Work
     trace: object = None            # trace_reduce.TraceSummary
+    #: ``repro.obs.snapshot()`` of the window, traced runs only
+    program: Optional[dict] = None
+    #: what the window's mechanism did, per tenant (``window_counts``)
+    counts: Dict[str, Dict[str, int]] = dataclasses.field(
+        default_factory=dict)
+
+    def program_share(self, name: str) -> Optional[float]:
+        """The summed time of the program's span ``name`` as a share of
+        the window (%); None where the tracer was off or never opened it."""
+        span = (self.program or {}).get("spans", {}).get(name)
+        if not span or not span["count"]:
+            return None
+        return 100.0 * span["total_s"] / self.window_s
 
 
 def reader(root: str, name: str):
@@ -388,6 +402,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     from repro.engine.state_matrix import StateMatrix
     from repro.kernels.decision_fused import decision_fused
 
+    try:
+        from repro import obs
+    except ImportError:                 # a program without its own tracer
+        obs = None
+    tracer = obs if trace else None
     meter = probes.CompileMeter(jax)
     spans = probes.Spans(jax)
     kernels = probes.KernelLaunches(compute, decision_fused)
@@ -433,9 +452,16 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         opts.host_tracer_level = 2
         jax.profiler.start_trace(TRACE_DIR, profiler_options=opts)
         spans.on = kernels.on = True
+        if tracer is not None:
+            tracer.reset()
+            tracer.enable()
     t0, done, started, submitted = serve_window(
         frontend, requests, stream.due, spans, jax.profiler.TraceAnnotation,
         stalls)
+    program = None
+    if tracer is not None:
+        tracer.disable()
+        program = tracer.snapshot()
     if trace:
         spans.on = kernels.on = False
         jax.profiler.stop_trace()
@@ -444,6 +470,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     stats = dev.memory_stats() or {}
     memory_peak = int(stats.get("peak_bytes_in_use", 0))
 
+    after = system.traces(frontend)
+    counts = window_counts(before, after, cfg)
     due_abs = t0 + stream.due
     served = ~np.isnan(done)
     run = Run(window_s=float(np.nanmax(done) - t0),
@@ -453,8 +481,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
               lateness_s=(submitted - due_abs)[served],
               engine_delta={k: timers1[k] - timers0[k] for k in timers0},
               compiles_in_window=compiles, device_kind=dev.device_kind,
-              spans=spans if trace else None, launches=kernels.launches)
-    after = system.traces(frontend)
+              spans=spans if trace else None, launches=kernels.launches,
+              program=program, counts=counts)
     fe_stats = frontend.stats()
     del frontend
     gc.collect()
@@ -467,7 +495,6 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
              f"{meter.compiles} compiles in {meter.seconds:.2f} s, "
              f"{meter.cache_hits} persistent-cache hits; "
              f"setup_s={setup_s:.3f}"]
-    counts = window_counts(before, after, cfg)
     late = run.lateness_s * 1e3
     lat = np.percentile(run.latencies_s * 1e3, [50, 90, 95, 99, 100])
     notes.append(
@@ -487,7 +514,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices()), "memory_peak_bytes": memory_peak}
     if trace:
-        summary = trace_reduce.reduce_trace(trace_reduce.find_xplane(TRACE_DIR))
+        summary = trace_reduce.reduce_trace(
+            trace_reduce.find_xplane(TRACE_DIR),
+            spans=(program or {}).get("spans", ()))
         run.trace = summary
         if summary is not None:
             device["busy_s"] = summary.busy_s
@@ -501,6 +530,15 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                 f"{len(run.launches)} launches recorded; spans "
                 + ", ".join(f"{k}={v:.3f}s/{spans.counts[k]}"
                             for k, v in sorted(spans.seconds.items())))
+    if program is not None:
+        notes.append(
+            "program tracer: spans "
+            + ", ".join(f"{k}={v['total_s']:.6f}s/{v['count']}"
+                        for k, v in sorted(program["spans"].items()))
+            + "; counters "
+            + ", ".join(f"{k}={v}" for k, v in sorted(
+                program["counters"].items()))
+            + f"; dropped {program['dropped']}")
     metrics = read_metrics(cell.root,
                            cell.per_layer if trace else cell.end_to_end, run)
 

@@ -131,7 +131,8 @@ def test_a_traced_run_reads_the_layers(tiny_root):
                               **dict(RUN, trace=True))
     assert result.correct, result.checks
     assert {"queue_wait_p95_ms", "decide_share", "window_compiles",
-            "plane_pass_share"} <= set(result.metrics)
+            "plane_pass_share", "plane_upload_share", "plane_kernel_share",
+            "plane_readback_share", "plane_refreshes"} <= set(result.metrics)
     # No device on the CPU: the device's metrics read nothing, never 0.
     assert "decision_fused_roofline" not in result.metrics
     assert "device_idle_share" not in result.metrics

@@ -35,6 +35,45 @@ def test_idle_time_goes_to_the_innermost_host_span():
     assert sum(got.values()) == sum(b - a for a, b in idle)
 
 
+class _TraceEvent:
+    def __init__(self, name, start_ns, end_ns):
+        self.name, self.start_ns, self.end_ns = name, start_ns, end_ns
+        self.stats = []
+
+
+class _TraceLine:
+    def __init__(self, name, events):
+        self.name, self.events = name, [_TraceEvent(*e) for e in events]
+
+
+class _TracePlane:
+    def __init__(self, name, lines):
+        self.name, self.lines = name, lines
+
+
+def test_idle_time_goes_to_a_program_span_the_run_recorded():
+    """A program span nested in ``plane_pass`` names the idle gap under it
+    once the run's snapshot names it; a span it does not name is not
+    admitted."""
+    host = _TracePlane("/host:CPU", [_TraceLine("main", [
+        ("window", 0, 100), ("pump", 10, 60), ("plane_pass", 20, 50),
+        ("fleet.pass", 22, 48), ("plane.readback", 30, 45),
+        ("unknown.span", 70, 80)])])
+    device = _TracePlane("/device:TPU:0", [_TraceLine(tr.OPS_LINE, [
+        ("decision_fused.1", 25, 30)])])
+    planes = [host, device]
+
+    def idle_ns(summary):
+        return {k: round(v * 1e9, 6) for k, v in summary.idle_by_host}
+    assert idle_ns(tr.reduce_planes(planes)) == {
+        tr.NO_SPAN: 50, "pump": 20, "plane_pass": 25}
+    got = tr.reduce_planes(planes, spans=["fleet.pass", "plane.readback"])
+    assert idle_ns(got) == {tr.NO_SPAN: 50, "pump": 20, "plane_pass": 4,
+                            "fleet.pass": 6, "plane.readback": 15}
+    assert got.busy_s == pytest.approx(5e-9) and got.kernel_events == 1
+    assert got.device_ops == tr.reduce_planes(planes).device_ops
+
+
 def test_op_names_drop_operands_and_layouts():
     name = ("%decision_fused.1 = f32[1,8,8,1024]{3,2,1,0:T(8,128)} "
             "custom-call(f32[8192]{0:T(1024)S(1)} %copy_bitcast_fusion.1)")

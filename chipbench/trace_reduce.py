@@ -8,9 +8,11 @@
 * a kernel's device time and event count: the operations whose name, or
   any of whose string stats, contains the kernel's name;
 * the device operations that took most time, and the device's idle time in
-  the window split by what the host was doing: the innermost of the
-  harness's host spans open at that moment, or ``no_span`` (the open loop
-  waiting for the next arrival).
+  the window split by what the host was doing: the innermost host span
+  open at that moment, or ``no_span`` (the open loop waiting for the next
+  arrival).  The host spans are the harness's (``HOST_SPANS``) and those
+  the caller names, such as the spans the program's own tracer recorded in
+  the window, each of which opens a profiler annotation of its name.
 """
 from __future__ import annotations
 
@@ -143,15 +145,24 @@ def _mentions(event, name: str) -> bool:
     return any(isinstance(v, str) and name in v for _, v in event.stats)
 
 
-def reduce_trace(path: str, kernel: str = "decision_fused",
-                 top: int = 10) -> Optional[TraceSummary]:
-    """The numbers of one trace; None where it holds no ``window`` span."""
+def reduce_trace(path: str, kernel: str = "decision_fused", top: int = 10,
+                 spans: Iterable[str] = ()) -> Optional[TraceSummary]:
+    """The numbers of one trace; None where it holds no ``window`` span.
+    ``spans`` names host spans admitted besides ``HOST_SPANS``."""
     from jax.profiler import ProfileData
-    data = ProfileData.from_file(path)
+    return reduce_planes(ProfileData.from_file(path).planes, kernel, top,
+                         spans)
+
+
+def reduce_planes(planes, kernel: str = "decision_fused", top: int = 10,
+                  spans: Iterable[str] = ()) -> Optional[TraceSummary]:
+    """:func:`reduce_trace` over a trace's planes (objects with the
+    ``name``, ``lines`` and ``events`` that ``ProfileData`` gives)."""
+    names = set(HOST_SPANS).union(spans)
     window: Optional[Interval] = None
     host: List[Tuple[float, float, str]] = []
     device_lines = []
-    for plane in data.planes:
+    for plane in planes:
         if DEVICE_PLANE.match(plane.name):
             device_lines += [ln for ln in plane.lines if ln.name == OPS_LINE]
             continue
@@ -159,7 +170,7 @@ def reduce_trace(path: str, kernel: str = "decision_fused",
             for ev in line.events:
                 if ev.name == WINDOW_SPAN:
                     window = (ev.start_ns, ev.end_ns)
-                elif ev.name in HOST_SPANS:
+                elif ev.name in names:
                     host.append((ev.start_ns, ev.end_ns, ev.name))
     if window is None:
         return None
@@ -169,17 +180,17 @@ def reduce_trace(path: str, kernel: str = "decision_fused",
     idle_total: Dict[str, float] = collections.defaultdict(float)
     segments = innermost(clip_spans(host, lo, hi))
     for line in device_lines:
-        spans = []
+        ops = []
         for ev in line.events:
             a, b = max(ev.start_ns, lo), min(ev.end_ns, hi)
             if b <= a:
                 continue
-            spans.append((a, b))
+            ops.append((a, b))
             by_op[op_name(ev.name)] += b - a
             if _mentions(ev, kernel):
                 kernel_ns += b - a
                 kernel_events += 1
-        busy = union(spans)
+        busy = union(ops)
         busy_ns += sum(b - a for a, b in busy)
         for name, ns in attribute(gaps(busy, lo, hi), segments,
                                   lo, hi).items():
