@@ -199,45 +199,46 @@ def fused_frames_scan(q_lo: np.ndarray, q_hi: np.ndarray, minsT: np.ndarray,
     the bit-identity contract must check :func:`float32_exact` first (see
     ``FleetMatrix._scanned_all``).  The frame count is padded up to a
     power of two, so passes of varying size compile a handful of kernel
-    shapes rather than one per size.
+    shapes rather than one per size; the padded frames are computed and
+    read back, and sliced away on the host.
 
     ``minsT``/``maxsT`` may instead be float32 device arrays, a plane kept
     resident on the device (``FleetMatrix``): they go to the kernel as
-    they are, and only the numpy operands are cast and copied up.
+    they are.  The numpy operands are cast to float32 on the host and
+    handed to the compiled program as they are, so their copy to the
+    device rides its dispatch.  The program thresholds the scan itself,
+    so a pass is one dispatch and one blocking readback of the mask.
 
     Traced (:mod:`repro.obs`) as the spans ``plane.stage`` (frame
-    padding), ``plane.upload`` (float32 cast and host-to-device copy of
-    the numpy operands), ``plane.kernel`` (the launch) and
-    ``plane.readback`` (device compare, wait and copy back), and the
-    counters ``plane.passes``, ``plane.h2d_bytes`` (bytes copied up) and
-    ``plane.d2h_bytes``.
+    padding), ``plane.upload`` (the host's float32 cast of the numpy
+    operands), ``plane.kernel`` (the one dispatch, which carries the
+    numpy operands to the device), ``plane.readback`` (the wait for the
+    mask and its copy back) and the counters ``plane.passes``,
+    ``plane.h2d_bytes`` (bytes copied up) and ``plane.d2h_bytes`` (bytes
+    of the padded mask copied back).
     """
     import jax
-    import jax.numpy as jnp
 
     from repro.kernels.decision_fused import decision_fused
 
     b = q_lo.shape[0]
-    traced = obs.enabled()
     with obs.span("plane.stage"):
         pad = ((0, (1 << (b - 1).bit_length()) - b), (0, 0), (0, 0))
         q_lo, q_hi = np.pad(q_lo, pad), np.pad(q_hi, pad)
-    sources = (q_lo, q_hi, minsT, maxsT)
     with obs.span("plane.upload"):
-        operands = [a if isinstance(a, jax.Array) else jnp.asarray(
-            a, jnp.float32) for a in sources]
-    h2d = sum(d.nbytes for a, d in zip(sources, operands)
-              if a is not d) if traced else 0
+        operands = [a if isinstance(a, jax.Array) else np.asarray(
+            a, np.float32) for a in (q_lo, q_hi, minsT, maxsT)]
     with obs.span("plane.kernel"):
-        scan, _, _ = decision_fused.fused_decision_pallas(*operands)
-    del operands        # the operands' device buffers go once the kernel ran
+        mask, _, _ = decision_fused.fused_decision_pallas(*operands,
+                                                          mask=True)
     with obs.span("plane.readback"):
-        out = np.asarray(scan[:b] > 0.5)
-    if traced:
+        out = np.asarray(mask)
+    if obs.enabled():
         obs.count("plane.passes")
-        obs.count("plane.h2d_bytes", h2d)
+        obs.count("plane.h2d_bytes", sum(
+            a.nbytes for a in operands if isinstance(a, np.ndarray)))
         obs.count("plane.d2h_bytes", out.nbytes)
-    return out
+    return out[:b]
 
 
 def _scan_matrix_fused(q_lo, q_hi, mins, maxs) -> np.ndarray:

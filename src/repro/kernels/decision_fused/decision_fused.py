@@ -112,8 +112,8 @@ def fused_decision_pallas(q_lo: jax.Array, q_hi: jax.Array,
                           inv_totals: Optional[jax.Array] = None,
                           w_lo: Optional[jax.Array] = None,
                           w_hi: Optional[jax.Array] = None,
-                          *, emit_scan: bool = True, bb: int = DEFAULT_BB,
-                          bp: int = DEFAULT_BP,
+                          *, emit_scan: bool = True, mask: bool = False,
+                          bb: int = DEFAULT_BB, bp: int = DEFAULT_BP,
                           interpret: Optional[bool] = None,
                           ) -> Tuple[Optional[jax.Array],
                                      Optional[jax.Array],
@@ -126,9 +126,12 @@ def fused_decision_pallas(q_lo: jax.Array, q_hi: jax.Array,
     of the returned triple is ``None`` when its inputs were not supplied
     (``cost`` needs ``rows`` (T, S, P) and ``inv_totals`` (T, S);
     ``freq`` needs the (W, C) window bounds) or, for ``scan``, when
-    ``emit_scan=False``.  ``bp`` must be a multiple of 128 (a plane of at
-    most ``bp`` partitions is one whole block).  ``interpret=None``
-    auto-selects via :func:`repro.kernels._backend.resolve_interpret`.
+    ``emit_scan=False``.  ``mask=True`` returns ``scan`` as the bool
+    ``scan > 0.5``, thresholded inside the same compiled program, so a
+    caller that wants the mask makes one device round trip.  ``bp`` must
+    be a multiple of 128 (a plane of at most ``bp`` partitions is one
+    whole block).  ``interpret=None`` auto-selects via
+    :func:`repro.kernels._backend.resolve_interpret`.
     """
     emit_cost = rows is not None
     emit_freq = w_lo is not None
@@ -137,16 +140,16 @@ def fused_decision_pallas(q_lo: jax.Array, q_hi: jax.Array,
     if bp % LANES:
         raise ValueError(f"bp={bp} must be a multiple of {LANES}")
     return _fused_call(q_lo, q_hi, p_min, p_max, rows, inv_totals, w_lo,
-                       w_hi, emit_scan=emit_scan, emit_cost=emit_cost,
-                       emit_freq=emit_freq, bb=bb, bp=bp,
+                       w_hi, emit_scan=emit_scan, mask=mask,
+                       emit_cost=emit_cost, emit_freq=emit_freq, bb=bb, bp=bp,
                        interpret=resolve_interpret(interpret))
 
 
-@functools.partial(jax.jit, static_argnames=("emit_scan", "emit_cost",
-                                             "emit_freq", "bb", "bp",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("emit_scan", "mask",
+                                             "emit_cost", "emit_freq", "bb",
+                                             "bp", "interpret"))
 def _fused_call(q_lo, q_hi, p_min, p_max, rows, inv_totals, w_lo, w_hi, *,
-                emit_scan, emit_cost, emit_freq, bb, bp, interpret):
+                emit_scan, mask, emit_cost, emit_freq, bb, bp, interpret):
     B, T, C = q_lo.shape
     _, _, S, P = p_min.shape
     bp = P if P <= bp else bp
@@ -219,6 +222,8 @@ def _fused_call(q_lo, q_hi, p_min, p_max, rows, inv_totals, w_lo, w_hi, *,
     )(*arrays)
     outs = list(outs)
     scan = outs.pop(0)[:B, :, :, :P] if emit_scan else None
+    if mask and emit_scan:
+        scan = scan > 0.5
     cost = (jnp.swapaxes(outs.pop(0).sum(axis=1)[..., 0], 0, 1)[:B]
             * inv_totals[None] if emit_cost else None)
     freq = outs.pop(0)[:, :, :P] if emit_freq else None

@@ -136,7 +136,8 @@ def test_results_are_bit_identical_with_tracing_on_and_off(untraced,
 def test_pass_counters_match_the_kernel_launches(traced):
     """The queries go up on every pass; a tenant's own plane goes up with
     its pass, the fleet's resident plane once for each plane version its
-    passes scored."""
+    passes scored.  The mask of every padded frame comes back, and the
+    host slices the padding away."""
     _, snap, shapes, launches, _, versions = traced
     counters = snap["counters"]
     assert launches and counters["plane.passes"] == len(launches)
@@ -153,7 +154,7 @@ def test_pass_counters_match_the_kernel_launches(traced):
             h2d += 2 * 4 * c * t * s * p
         else:
             resident[id(plane)] = plane
-        d2h += b * t * s * p                    # one bool per partition
+        d2h += b_pad * t * s * p    # the padded mask, one bool a partition
     h2d += sum(2 * 4 * plane.size for plane in resident.values())
     assert versions and len(set(versions)) < len(versions)
     assert counters["plane.refreshes"] == len(set(versions)) \

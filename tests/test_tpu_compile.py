@@ -54,7 +54,8 @@ def no_persistent_cache():
     cc.reset_cache()
 
 
-def _compile(sharding, *, B, T, S, P, emit_scan=True, cost=False, W=0):
+def _compile(sharding, *, B, T, S, P, emit_scan=True, mask=False,
+             cost=False, W=0):
     def sds(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
 
@@ -62,7 +63,7 @@ def _compile(sharding, *, B, T, S, P, emit_scan=True, cost=False, W=0):
         sds(B, T, C), sds(B, T, C), sds(C, T, S, P), sds(C, T, S, P),
         sds(T, S, P) if cost else None, sds(T, S) if cost else None,
         sds(W, C) if W else None, sds(W, C) if W else None,
-        emit_scan=emit_scan, emit_cost=cost, emit_freq=bool(W),
+        emit_scan=emit_scan, mask=mask, emit_cost=cost, emit_freq=bool(W),
         bb=df.DEFAULT_BB, bp=df.DEFAULT_BP, interpret=False).compile()
 
 
@@ -84,7 +85,8 @@ def test_fleet_scan_compiles_for_v5e(one_chip, no_persistent_cache, B, T_cap,
                                      S_cap, P_cap):
     """The FleetMatrix pass over the packed plane; the smoke's plane has
     capacity for 2T tenant rows and 8 states."""
-    _assert_fits_chip(_compile(one_chip, B=B, T=T_cap, S=S_cap, P=P_cap))
+    _assert_fits_chip(_compile(one_chip, B=B, T=T_cap, S=S_cap, P=P_cap,
+                               mask=True))
 
 
 @pytest.mark.parametrize("S_cap,P_cap", [(8, P), (16, P), (8, 1158)])
@@ -93,7 +95,8 @@ def test_state_matrix_scan_compiles_for_v5e(one_chip, no_persistent_cache,
     """StateMatrix scores one query against its whole (C, S_cap, P_cap)
     twin: B=1 frame, one tenant.  Hybrid layouts mid-migration give a
     P_cap off the 128-lane tiling, padded up inside the wrapper."""
-    _assert_fits_chip(_compile(one_chip, B=1, T=1, S=S_cap, P=P_cap))
+    _assert_fits_chip(_compile(one_chip, B=1, T=1, S=S_cap, P=P_cap,
+                               mask=True))
 
 
 @pytest.mark.parametrize("B,S,W_", [
